@@ -30,6 +30,8 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated module substrings")

@@ -258,7 +258,6 @@ class AutotuneConfig:
     enabled: bool = False
     cache_dir: str = ""                          # "" -> in-memory only
     iters: int = 3                               # timing reps per variant
-    device_kind: str = "tpu_v5e"                 # autotune.device registry key
     # kernels to tune at startup; flash_attention / ssd_scan can be added
     # where their tuning cost is worth it
     kernels: Tuple[str, ...] = ("quantize", "dequantize")

@@ -15,18 +15,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-    """Version-portable shard_map: ``jax.shard_map`` on new JAX, the
-    ``jax.experimental`` spelling (with ``check_vma`` -> ``check_rep``)
-    on older releases."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    if "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
 # logical axis -> mesh axis (or tuple of mesh axes, or None = replicate)
 DEFAULT_RULES = {
     "batch": ("pod", "data"),       # DP across pods and the data axis

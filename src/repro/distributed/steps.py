@@ -201,6 +201,17 @@ def make_apply_step(cfg: ModelConfig, tcfg: TrainConfig):
     return apply_step
 
 
+def jit_apply_step(cfg: ModelConfig, tcfg: TrainConfig):
+    """The update as the trainer dispatches it.  Params, optimizer state
+    and grads are dead after it, so all three are donated and the new state
+    is written in place.  The update reads only the f32 master copy, so
+    ``keep_unused`` keeps the bf16 params an input of the program: pruned,
+    their donation would be lost and each step would allocate new params
+    beside the old ones."""
+    return jax.jit(make_apply_step(cfg, tcfg), donate_argnums=(0, 1, 2),
+                   keep_unused=True)
+
+
 def make_eval_step(cfg: ModelConfig, policy=None):
     api = get_api(cfg)
 

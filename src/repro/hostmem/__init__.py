@@ -83,9 +83,10 @@ class HostMemTier:
         :class:`~repro.kernels.autotune.tuner.Autotuner`."""
         from repro.common.config import AutotuneConfig
         from repro.kernels.autotune import (Autotuner, AutotuneCache,
+                                            current_device_kind,
                                             get_device_spec, install_cache)
         atcfg = atcfg or AutotuneConfig(enabled=True)
-        kind = device_kind or atcfg.device_kind
+        kind = device_kind or current_device_kind()
         cache = (AutotuneCache.load(atcfg.cache_dir, device_kind=kind)
                  if atcfg.cache_dir else AutotuneCache(device_kind=kind))
         tuner = Autotuner(cache=cache, spec=get_device_spec(kind),

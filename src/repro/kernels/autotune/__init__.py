@@ -21,8 +21,9 @@ from __future__ import annotations
 from repro.kernels.autotune.advisor import CompressionAdvisor
 from repro.kernels.autotune.cache import (AutotuneCache, SCHEMA_VERSION,
                                           cache_key)
-from repro.kernels.autotune.device import (DEFAULT_DEVICE_KIND, DEVICE_SPECS,
-                                           DeviceSpec, get_device_spec)
+from repro.kernels.autotune.device import (DEVICE_SPECS, DeviceSpec,
+                                           current_device_kind,
+                                           get_device_spec)
 from repro.kernels.autotune.table import (clear, install, installed_count,
                                           shape_bucket, table_key,
                                           tuned_config)
@@ -31,8 +32,9 @@ from repro.kernels.autotune.tuner import (HOST_LINK_KERNEL, Autotuner,
 
 __all__ = [
     "Autotuner", "AutotuneCache", "CompressionAdvisor", "DeviceSpec",
-    "DEVICE_SPECS", "DEFAULT_DEVICE_KIND", "HOST_LINK_KERNEL",
-    "SCHEMA_VERSION", "cache_key", "clear", "default_measure",
+    "DEVICE_SPECS", "HOST_LINK_KERNEL",
+    "SCHEMA_VERSION", "cache_key", "clear", "current_device_kind",
+    "default_measure",
     "get_device_spec", "install", "install_cache", "installed_count",
     "shape_bucket", "table_key", "tuned_config",
 ]

@@ -19,6 +19,7 @@ import json
 import os
 from typing import Dict, Optional, Sequence
 
+from repro.kernels.autotune.device import current_device_kind
 from repro.kernels.autotune.table import dtype_name, shape_bucket
 
 SCHEMA_VERSION = 1
@@ -35,9 +36,11 @@ class AutotuneCache:
     """In-memory entry map + the optional directory it mirrors to."""
 
     def __init__(self, directory: str = "",
-                 device_kind: str = "tpu_v5e"):
+                 device_kind: Optional[str] = None):
         self.dir = directory
-        self.device_kind = device_kind
+        # entries are keyed by the device they were measured on: the one
+        # this process runs on unless the caller names another
+        self.device_kind = device_kind or current_device_kind()
         self.entries: Dict[str, dict] = {}
         self.bwmodel: Optional[dict] = None    # BandwidthModel.to_dict()
         self.load_errors = 0                   # unreadable files skipped
@@ -87,10 +90,10 @@ class AutotuneCache:
 
     @classmethod
     def load(cls, directory: str,
-             device_kind: str = "tpu_v5e") -> "AutotuneCache":
+             device_kind: Optional[str] = None) -> "AutotuneCache":
         """Load a cache dir; any corruption yields an empty cache with
         ``load_errors`` counted (re-tuning is the recovery path)."""
-        cache = cls(directory, device_kind)
+        cache = cls(directory, device_kind=device_kind)
         path = cache.path
         if not path or not os.path.exists(path):
             return cache

@@ -1,13 +1,12 @@
 """Device roofline specs (one source of truth).
 
-``launch/roofline.py`` and the kernel autotuner used to carry their own
-copies of the TPU v5e hardware constants; both now read one
+``launch/roofline.py`` and the kernel autotuner read one
 :class:`DeviceSpec` selected by device kind, so the dry-run roofline
 report and the autotuner's achieved-vs-peak efficiency are judged
-against the same peaks.  The registry covers the targets the repo talks
-about; unknown kinds fall back to the v5e numbers (the paper's target)
-rather than crashing — an autotune cache records which kind it was
-measured on, so a mismatched spec is visible, never silent.
+against the same peaks.  The kind is read from the device JAX reports
+(``jax.devices()[0].device_kind``, mapped onto the registry by
+:data:`JAX_DEVICE_KINDS`); a kind the registry does not know is an
+error, never a silent fallback to another chip's peaks.
 """
 from __future__ import annotations
 
@@ -28,11 +27,9 @@ class DeviceSpec:
         return asdict(self)
 
 
-DEFAULT_DEVICE_KIND = "tpu_v5e"
-
 DEVICE_SPECS: Dict[str, DeviceSpec] = {
-    # paper target: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI,
-    # 32 GB/s host link (the Eq. 3 constant)
+    # Google Cloud "TPU v5e" page: 197 TFLOP/s bf16, 819 GB/s HBM;
+    # ~50 GB/s/link ICI, 32 GB/s host link (the paper's Eq. 3 constant)
     "tpu_v5e": DeviceSpec("tpu_v5e", 197e12, 819e9, 50e9, 32e9),
     "tpu_v5p": DeviceSpec("tpu_v5p", 459e12, 2765e9, 100e9, 32e9),
     "tpu_v4": DeviceSpec("tpu_v4", 275e12, 1228e9, 50e9, 32e9),
@@ -41,16 +38,35 @@ DEVICE_SPECS: Dict[str, DeviceSpec] = {
     "cpu": DeviceSpec("cpu", 1e12, 50e9, 10e9, 32e9),
 }
 
+# what ``jax.devices()[0].device_kind`` reports -> registry row
+JAX_DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5": "tpu_v5p",
+    "TPU v4": "tpu_v4",
+    "cpu": "cpu",
+}
+
+
+def registry_kind(kind: str) -> str:
+    """Registry key for a registry key or a JAX ``device_kind`` string;
+    raises ``ValueError`` on a kind the registry does not know."""
+    if kind in DEVICE_SPECS:
+        return kind
+    try:
+        return JAX_DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown device kind {kind!r}: add its peaks to DEVICE_SPECS "
+            f"and its JAX name to JAX_DEVICE_KINDS") from None
+
+
+def current_device_kind() -> str:
+    """Registry key of the device this process runs on."""
+    import jax
+    return registry_kind(jax.devices()[0].device_kind)
+
 
 def get_device_spec(kind: Optional[str] = None) -> DeviceSpec:
-    """Spec for ``kind`` (default: the paper's TPU v5e target).  Unknown
-    kinds fall back to the default spec's numbers under the asked-for
-    name so cache keys still record what the caller believed it had."""
-    if not kind:
-        return DEVICE_SPECS[DEFAULT_DEVICE_KIND]
-    spec = DEVICE_SPECS.get(kind)
-    if spec is None:
-        base = DEVICE_SPECS[DEFAULT_DEVICE_KIND]
-        return DeviceSpec(kind, base.peak_flops, base.hbm_bw,
-                          base.ici_bw, base.host_bw)
-    return spec
+    """Spec for ``kind`` (a registry key or a JAX ``device_kind``), or for
+    the device this process runs on when ``kind`` is None."""
+    return DEVICE_SPECS[registry_kind(kind) if kind else current_device_kind()]

@@ -25,6 +25,7 @@ NEG_INF = -1e30
 def _attn_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                  acc_scr, *, causal: bool, sm_scale: float, block_q: int,
                  block_k: int, num_kv_blocks: int):
+    kv_len = lens_ref[pl.program_id(0)]
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -42,7 +43,7 @@ def _attn_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                                 preferred_element_type=jnp.float32)  # (bq,bk)
         kpos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(kpos < lens_ref[0, 0], s, NEG_INF)     # padded-KV mask
+        s = jnp.where(kpos < kv_len, s, NEG_INF)             # padded-KV mask
         if causal:
             qpos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -86,12 +87,11 @@ def flash_attention_fwd(q, k, v, *, causal: bool, sm_scale: float,
         block_q=block_q, block_k=block_k, num_kv_blocks=nk)
     if kv_lens is None:
         kv_lens = jnp.full((B,), Sk, jnp.int32)
-    lens2 = kv_lens.reshape(B, 1).astype(jnp.int32)
     return pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, qi, ki: (b, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((None, None, block_q, D),
                          lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((None, None, block_k, D),
@@ -108,12 +108,13 @@ def flash_attention_fwd(q, k, v, *, causal: bool, sm_scale: float,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(lens2, q, k, v)
+    )(kv_lens.astype(jnp.int32), q, k, v)
 
 
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, sm_scale: float, block_k: int,
                    num_kv_blocks: int):
+    kv_len = lens_ref[pl.program_id(0)]
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -128,7 +129,7 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (1,bk)
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < lens_ref[0, 0], s, NEG_INF)
+    s = jnp.where(kpos < kv_len, s, NEG_INF)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -155,14 +156,13 @@ def flash_decode_fwd(q, k, v, lens, *, sm_scale: float, block_k: int = 128,
     block_k = min(block_k, Sk)
     assert Sk % block_k == 0
     nk = Sk // block_k
-    lens2 = lens.reshape(B, 1).astype(jnp.int32)
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                block_k=block_k, num_kv_blocks=nk)
     return pl.pallas_call(
         kernel,
         grid=(B, H, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ki: (b, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((None, None, 1, D), lambda b, h, ki: (b, h, 0, 0)),
             pl.BlockSpec((None, None, block_k, D),
                          lambda b, h, ki: (b, h // G, ki, 0)),
@@ -178,4 +178,4 @@ def flash_decode_fwd(q, k, v, lens, *, sm_scale: float, block_k: int = 128,
             pltpu.VMEM((1, D), jnp.float32),
         ],
         interpret=interpret,
-    )(lens2, q, k, v)
+    )(lens.astype(jnp.int32), q, k, v)

@@ -37,6 +37,10 @@ Policy modes for train cells:
   offload_all  WarmUp-stage conservative policy
 """
 
+# the production pod these meshes model is a TPU v5e slice; its peaks
+# bound the roofline terms whatever backend runs the dry run itself
+TARGET_KIND = "tpu_v5e"
+
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                          "artifacts", "dryrun")
 
@@ -48,7 +52,8 @@ def _zero_stage(arch: str) -> int:
 def _estimate_t_iter(cfg, shape, chips: int) -> float:
     tokens = shape.global_batch * shape.seq_len
     mf = R.model_flops_train(cfg.active_param_count(), tokens)
-    return mf / (chips * R.PEAK_FLOPS * 0.4)   # assume 40% MFU
+    peak = R.get_device_spec(TARGET_KIND).peak_flops
+    return mf / (chips * peak * 0.4)           # assume 40% MFU
 
 
 # sites whose activation shards on BOTH batch (dp) and model (tp) axes
@@ -243,8 +248,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0 - t_lower
 
         ma = compiled.memory_analysis()
-        terms = R.analyze(compiled, chips, model_flops=mf,
-                          step_jaxpr=step_cj)
+        terms = R.analyze(compiled, chips, device_kind=TARGET_KIND,
+                          model_flops=mf, step_jaxpr=step_cj)
 
     hbm = ChameleonConfig().hbm_budget_bytes
     per_chip = (ma.argument_size_in_bytes + ma.temp_size_in_bytes

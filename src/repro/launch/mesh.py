@@ -14,29 +14,25 @@ import jax
 from repro.common.config import MeshConfig, MULTI_POD_MESH, SINGLE_POD_MESH
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer JAX (>= 0.5 explicit-sharding
-    line); older versions default every axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_from_config(mc: MeshConfig):
-    return jax.make_mesh(mc.shape, mc.axes, **_axis_type_kwargs(len(mc.axes)))
+    return _auto_mesh(mc.shape, mc.axes)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
                    axes: Tuple[str, ...] = ("data", "model")):
     """Small mesh for CPU tests (requires host-platform device override)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return _auto_mesh(shape, axes)
 
 
 def mesh_config(multi_pod: bool) -> MeshConfig:

@@ -24,9 +24,9 @@ handled explicitly:
 HBM bytes use the same jaxpr walk (dot operands/outputs + tagged residual
 stores), a post-fusion traffic proxy: elementwise chains fuse into the
 surrounding matmuls on TPU.  Hardware constants come from the shared
-:class:`~repro.kernels.autotune.device.DeviceSpec` registry (TPU v5e
-default: 197 TFLOP/s bf16 · 819 GB/s HBM · ~50 GB/s/link ICI · 32 GB/s
-host link) — one spec feeds this report and the kernel autotuner.
+:class:`~repro.kernels.autotune.device.DeviceSpec` registry, for the
+device kind the caller names — one spec feeds this report and the kernel
+autotuner.
 """
 from __future__ import annotations
 
@@ -37,16 +37,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.kernels.autotune.device import (DEVICE_SPECS, DeviceSpec,
-                                           get_device_spec)
-
-_DEFAULT_SPEC = get_device_spec()
-# module-level aliases kept for existing callers/tests; the spec registry
-# is the source of truth
-PEAK_FLOPS = _DEFAULT_SPEC.peak_flops
-HBM_BW = _DEFAULT_SPEC.hbm_bw
-ICI_BW = _DEFAULT_SPEC.ici_bw
-HOST_BW = _DEFAULT_SPEC.host_bw
+from repro.kernels.autotune.device import DeviceSpec, get_device_spec
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -274,8 +265,7 @@ class RooflineTerms:
     step_time_bound_s: float = 0.0
     mfu_bound: float = 0.0
 
-    def finalize(self, spec: Optional[DeviceSpec] = None):
-        spec = spec or _DEFAULT_SPEC
+    def finalize(self, spec: DeviceSpec):
         self.compute_s = self.flops_per_chip / spec.peak_flops
         self.memory_s = self.bytes_per_chip / spec.hbm_bw
         self.collective_s = self.wire_bytes_per_chip / spec.ici_bw
@@ -296,13 +286,10 @@ class RooflineTerms:
         return asdict(self)
 
 
-def analyze(compiled, chips: int, model_flops: float = 0.0,
-            hlo_text: Optional[str] = None,
-            step_jaxpr=None,
-            device_kind: Optional[str] = None) -> RooflineTerms:
+def analyze(compiled, chips: int, *, device_kind: str,
+            model_flops: float = 0.0, hlo_text: Optional[str] = None,
+            step_jaxpr=None) -> RooflineTerms:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # older JAX: one dict per program
-        cost = cost[0] if cost else {}
     txt = hlo_text if hlo_text is not None else compiled.as_text()
     colls = collective_bytes(txt)
     xla_flops = float(cost.get("flops", 0.0))
@@ -323,8 +310,7 @@ def analyze(compiled, chips: int, model_flops: float = 0.0,
         xla_bytes_per_chip=xla_bytes,
         model_flops=model_flops,
     )
-    return terms.finalize(get_device_spec(device_kind)
-                          if device_kind else None)
+    return terms.finalize(get_device_spec(device_kind))
 
 
 def model_flops_train(param_count: int, tokens: int) -> float:

@@ -1,14 +1,11 @@
-"""Production training entry point.
-
-Single-host CPU (reduced configs) or multi-host TPU (full configs):
+"""Training entry point, on one device: the CPU with a reduced config, or
+one TPU chip at a config's published widths.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b \
         --reduced --steps 50
 
-On a real cluster each host runs this under the pod launcher (see
-launch/scripts/) with JAX_COORDINATOR_ADDRESS etc. set; jax.distributed
-initializes from env and the per-host data shards come from
-process_index/process_count.
+``chip_smoke.py`` builds its trainer through :func:`parse_args` and
+:func:`build_trainer`, so the chip check runs this exact setup.
 """
 from __future__ import annotations
 
@@ -16,7 +13,7 @@ import argparse
 import os
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2-paper")
     ap.add_argument("--reduced", action="store_true",
@@ -53,10 +50,6 @@ def main():
                          "(drift never stalls an iteration); speculative "
                          "additionally pre-generates policies for "
                          "predicted-recurring op sequences")
-    ap.add_argument("--multihost", action="store_true",
-                    help="initialize jax.distributed from env")
-    ap.add_argument("--mesh", choices=["none", "single", "multi"],
-                    default="none")
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--trace-out", default="",
                     help="write a Chrome trace-event JSON here on exit "
@@ -76,19 +69,19 @@ def main():
     ap.add_argument("--audit-out", default="",
                     help="write the repro.obs audit log (JSONL) here on "
                          "exit — the evidence trail for fault drills")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.multihost:
-        import jax
-        jax.distributed.initialize()
 
+def build_trainer(args: argparse.Namespace):
+    """Config, data and :class:`Trainer` (with its ChameleonRuntime) for
+    parsed arguments.  The data prefetcher is started; the caller stops
+    it (``tr.data.stop()``) and closes the runtime (``tr.rt.close()``)."""
     import jax
     import repro.configs as C
     from repro.common.config import (AdaptConfig, AutotuneConfig,
                                      ChameleonConfig, PolicyStoreConfig,
                                      TrainConfig)
     from repro.data.synthetic import SyntheticTokens
-    from repro.launch.mesh import make_production_mesh
     from repro.runtime.trainer import Trainer
 
     cfg = C.get_reduced(args.arch) if args.reduced else C.get_config(args.arch)
@@ -109,12 +102,22 @@ def main():
                            adapt=AdaptConfig(mode=args.adapt_mode),
                            autotune=AutotuneConfig(
                                enabled=args.autotune, cache_dir=at_dir))
-    mesh = None
-    if args.mesh != "none":
-        mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
     data = SyntheticTokens(cfg.vocab_size, seq, gb,
                            host_index=jax.process_index(),
                            host_count=jax.process_count()).start()
+    try:
+        return Trainer(cfg, tcfg, cham, data=data,
+                       metrics_out=args.metrics_out or None,
+                       metrics_every=args.metrics_every)
+    except BaseException:
+        data.stop()
+        raise
+
+
+def main(argv=None):
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    args = parse_args(argv)
     if args.audit_out:
         # stream every audit event (not just the in-memory tail): the
         # chaos-drill evidence trail must survive a crash
@@ -123,11 +126,8 @@ def main():
     if args.fault_plan:
         from repro import faults
         faults.arm(faults.FaultPlan.load(args.fault_plan))
-    tr = None
+    tr = build_trainer(args)
     try:
-        tr = Trainer(cfg, tcfg, cham, mesh=mesh, data=data,
-                     metrics_out=args.metrics_out or None,
-                     metrics_every=args.metrics_every)
         if args.resume:
             tr.resume()
         rep = tr.train(args.steps)
@@ -163,20 +163,19 @@ def main():
                   f"discarded={ad['discarded']} failed={ad['failed']} "
                   f"spec_hits={ad['speculative_hits']}")
     finally:
-        data.stop()
+        tr.data.stop()
         if args.fault_plan:
             from repro import faults
             plan = faults.active()
             if plan is not None:
                 print(f"fault plan: fired={plan.stats()['fired']}")
             faults.disarm()
-        if tr is not None:
-            lad = tr.rt.ladder
-            if lad is not None and lad.transitions:
-                print(f"ladder: rung={lad.name} "
-                      f"descents={lad.n_descents} ascents={lad.n_ascents}")
-            tr.rt.close()
-            _export_obs(args, tr.rt)
+        lad = tr.rt.ladder
+        if lad is not None and lad.transitions:
+            print(f"ladder: rung={lad.name} "
+                  f"descents={lad.n_descents} ascents={lad.n_ascents}")
+        tr.rt.close()
+        _export_obs(args, tr.rt)
 
 
 def _export_obs(args, rt) -> None:

@@ -126,7 +126,7 @@ def apply_moe_ep(cfg: ModelConfig, p, x):
             aux = jax.lax.pmean(aux, batch_axes)
         return out, aux
 
-    sm = shd.shard_map(
+    sm = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(batch_axes or None, None, None),   # x
                   P(None, None),                        # router

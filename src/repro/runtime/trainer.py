@@ -7,8 +7,8 @@ varies — loss-scale skips shorten it, eval extends it — and the Chameleon
 runtime tracks it exactly as §4 describes.
 
 Fault tolerance: async sharded checkpoints on a cadence, emergency
-checkpoint on exception, ``resume()`` from the latest step (optionally onto
-a different mesh — elastic restart), straggler detection on step times.
+checkpoint on exception, ``resume()`` from the latest step, straggler
+detection on step times.  One device: a mesh is refused, not ignored.
 """
 from __future__ import annotations
 
@@ -27,7 +27,6 @@ from repro.common.config import (AdaptConfig, ChameleonConfig, ModelConfig,
                                  TrainConfig)
 from repro.core.runtime import ChameleonRuntime
 from repro.data.synthetic import SyntheticTokens
-from repro.distributed import sharding as shd
 from repro.distributed import steps as S
 from repro.models.registry import get_api
 from repro.optim.adamw import adamw_init
@@ -78,15 +77,27 @@ class Trainer:
             self.cham = dataclasses.replace(
                 self.cham,
                 adapt=dataclasses.replace(self.cham.adapt, mode=adapt_mode))
-        self.mesh = mesh
+        if mesh is not None:
+            # nothing below shards the params, optimizer state or batch: a
+            # mesh would be accepted and silently ignored
+            raise NotImplementedError(
+                "Trainer runs on one device; sharded training state is not "
+                "implemented")
         self.api = get_api(cfg)
         self.data = data or SyntheticTokens(cfg.vocab_size, 128, 8,
                                             seed=tcfg.seed)
         self.eval_data = eval_data or SyntheticTokens(
             cfg.vocab_size, self.data.seq_len, self.data.global_batch,
             seed=tcfg.seed + 1)
-        self.params, _ = self.api.init(cfg, jax.random.PRNGKey(tcfg.seed))
-        self.opt_state = adamw_init(self.params)
+
+        def init_state(key):
+            params = self.api.init(cfg, key)[0]
+            return params, adamw_init(params)
+
+        # one program lays the whole state out in HBM at once; eager init
+        # runs hundreds of small ops whose freed temporaries fragment it
+        self.params, self.opt_state = jax.jit(init_state)(
+            jax.random.PRNGKey(tcfg.seed))
         self.loss_scale = init_loss_scale(tcfg.loss_scale)
         self.step = 0
         self.straggler = StragglerDetector(on_straggler=self._on_straggler)
@@ -105,7 +116,7 @@ class Trainer:
             tcfg.checkpoint_dir, keep=tcfg.keep_checkpoints,
             engine=self.rt.hostmem.engine if self.rt.hostmem else None,
             on_error="degrade" if self.cham.resilience.enabled else "raise")
-        self._apply = jax.jit(S.make_apply_step(cfg, tcfg))
+        self._apply = S.jit_apply_step(cfg, tcfg)
         self._eval = jax.jit(S.make_eval_step(cfg))
         self._prepared = False
         # repro.obs: scattered stats() dicts register as lazy providers so

@@ -19,7 +19,7 @@ from repro.kernels.autotune.advisor import (COMPRESS_INT8, COMPRESS_RAW,
                                             CompressionAdvisor)
 from repro.kernels.autotune.cache import (CACHE_FILENAME, SCHEMA_VERSION,
                                           AutotuneCache, cache_key)
-from repro.kernels.autotune.device import (DEFAULT_DEVICE_KIND, DEVICE_SPECS,
+from repro.kernels.autotune.device import (DEVICE_SPECS, current_device_kind,
                                            get_device_spec)
 from repro.kernels.autotune.space import SPACES
 from repro.kernels.autotune.tuner import HOST_LINK_KERNEL, Autotuner
@@ -35,22 +35,28 @@ def _clean_table():
 
 # ---------------------------------------------------------- device spec
 def test_device_spec_registry():
-    spec = get_device_spec()
-    assert spec.kind == DEFAULT_DEVICE_KIND
+    spec = get_device_spec()                       # the device JAX reports
+    assert spec.kind == current_device_kind() == "cpu"
     assert spec.hbm_bw > 0 and spec.host_bw > 0
     assert set(DEVICE_SPECS) >= {"tpu_v5e", "tpu_v5p", "tpu_v4", "cpu"}
-    unknown = get_device_spec("tpu_v9x")
-    assert unknown.kind == "tpu_v9x"              # asked-for name kept
-    assert unknown.hbm_bw == DEVICE_SPECS["tpu_v5e"].hbm_bw
+    # a v5e reports itself as "TPU v5 lite"
+    assert get_device_spec("TPU v5 lite") is DEVICE_SPECS["tpu_v5e"]
+    with pytest.raises(ValueError, match="tpu_v9x"):
+        get_device_spec("tpu_v9x")                 # no silent fallback
     d = spec.to_dict()
     assert d["kind"] == spec.kind and d["hbm_bw"] == spec.hbm_bw
 
 
 def test_roofline_uses_device_spec():
-    from repro.launch import roofline
-    spec = get_device_spec()
-    assert roofline.PEAK_FLOPS == spec.peak_flops
-    assert roofline.HBM_BW == spec.hbm_bw
+    from repro.launch.roofline import RooflineTerms
+    for kind in ("tpu_v5e", "tpu_v4"):
+        spec = DEVICE_SPECS[kind]
+        t = RooflineTerms(flops_per_chip=spec.peak_flops,
+                          bytes_per_chip=spec.hbm_bw / 2,
+                          wire_bytes_per_chip=0.0, collectives={},
+                          chips=1).finalize(get_device_spec(kind))
+        assert t.compute_s == pytest.approx(1.0)
+        assert t.memory_s == pytest.approx(0.5)
 
 
 # ------------------------------------------------------- keys / buckets

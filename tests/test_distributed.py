@@ -102,7 +102,7 @@ def test_compressed_grad_sync_int8_on_wire():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import make_compressed_grad_sync
-from repro.distributed.sharding import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_test_mesh
 
 mesh = make_test_mesh((4, 2), ('pod', 'model'))

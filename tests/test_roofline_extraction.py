@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.autotune.device import DEVICE_SPECS
 from repro.launch.roofline import (collective_bytes, jaxpr_cost,
                                    model_flops_train, RooflineTerms)
 
@@ -88,7 +89,7 @@ def test_roofline_terms_bottleneck():
         bytes_per_chip=819e9 / 2,    # 0.5 s memory
         wire_bytes_per_chip=50e9 * 2,  # 2 s collective
         collectives={}, chips=256,
-        model_flops=0.8 * 197e12 * 256).finalize()
+        model_flops=0.8 * 197e12 * 256).finalize(DEVICE_SPECS["tpu_v5e"])
     assert t.bottleneck == "collective"
     assert t.step_time_bound_s == pytest.approx(2.0)
     assert t.mfu_bound == pytest.approx(0.4)

@@ -37,6 +37,14 @@ def _trainer(tmpdir, *, cham=False, eval_every=0, steps=30, seed=0,
                    data=data)
 
 
+def test_trainer_refuses_mesh(tmpdir):
+    """No training path shards state: a mesh must fail loudly, not be
+    stored and ignored."""
+    cfg = C.get_reduced("llama2_paper")
+    with pytest.raises(NotImplementedError):
+        Trainer(cfg, TrainConfig(checkpoint_dir=tmpdir), mesh=object())
+
+
 def test_loss_decreases(tmpdir):
     tr = _trainer(tmpdir, steps=25)
     rep = tr.train(25)
