@@ -217,19 +217,28 @@ class ChameleonRuntime:
             if tl.peak > self.budget:
                 try:
                     sites = warmup_offload_sites(prof, self.cfg, self.budget)
-                    self.applied = AppliedPolicy(
+                    self._install(AppliedPolicy(
                         None, sites,
                         self.executor.site_universe(prof) - sites, set(),
-                        "warmup:" + ",".join(sorted(sites)))
+                        "warmup:" + ",".join(sorted(sites))), "prepare")
                     kind = "warmup"
                 except ChameleonOOMError:
-                    self.applied = self.executor.conservative(prof)
+                    self._install(self.executor.conservative(prof), "prepare")
                     kind = "conservative"
             else:
-                self.applied = self.executor.baseline()
+                self._install(self.executor.baseline(), "prepare")
                 kind = "baseline"
             self._audit_apply(kind)
         return self.applied
+
+    def _install(self, applied: AppliedPolicy, reason: str) -> None:
+        """Make ``applied`` the policy the next iteration runs.  A change
+        of fingerprint is a ``policy.install`` instant on the adapt lane,
+        with ``reason`` as its arg, and counts in ``policy_installs``."""
+        if applied.fingerprint != self.applied.fingerprint:
+            obs.tracer().instant(obs.LANE_ADAPT, "policy.install", arg=reason)
+            obs.metrics().counter("policy_installs")
+        self.applied = applied
 
     def _audit_apply(self, kind: str, knob: Optional[float] = None) -> None:
         """Audit-log the policy taking effect (repro.obs drift trail)."""
@@ -266,7 +275,7 @@ class ChameleonRuntime:
                                              exact_hit=exact)
             if hit is not None:
                 self._last_decision = decision
-                self.applied = hit.applied
+                self._install(hit.applied, "prepare")
                 if hit.profile is not None:
                     # the schedule remapped: engine feedback follows it
                     self.profile = hit.profile
@@ -322,28 +331,91 @@ class ChameleonRuntime:
         """Lightweight mode: token stream of this dispatch (trace cached by
         arg shapes, so steady-state cost is a dict lookup + append)."""
         t0 = time.perf_counter()
-        key = (name, self.applied.fingerprint) + self._args_key(args)
-        toks = self._trace_cache.get(key)
-        if toks is None:
-            import jax
-            try:
-                traced = fn.trace(*args)          # jitted fn
-                cj = traced.jaxpr
-            except AttributeError:
-                cj = jax.make_jaxpr(fn)(*args)
-            toks = tokenizer.tokenize_jaxpr_stream(cj)
-            self._trace_cache[key] = toks
-        self._iter_streams.append(toks)
-        if name == "train":
-            self._last_train_args = args
-            self._train_shape = key[2:]           # arg shapes/dtypes only
+        with obs.tracer().span(obs.LANE_HOST, "runtime.record_dispatch",
+                               arg=name):
+            key = (name, self.applied.fingerprint) + self._args_key(args)
+            toks = self._trace_cache.get(key)
+            if toks is None:
+                import jax
+                try:
+                    traced = fn.trace(*args)          # jitted fn
+                    cj = traced.jaxpr
+                except AttributeError:
+                    cj = jax.make_jaxpr(fn)(*args)
+                toks = tokenizer.tokenize_jaxpr_stream(cj)
+                self._trace_cache[key] = toks
+            self._iter_streams.append(toks)
+            if name == "train":
+                self._last_train_args = args
+                self._train_shape = key[2:]           # arg shapes/dtypes only
         self.profiling_overhead_s += time.perf_counter() - t0
 
     def end_iteration(self, t_iter: float) -> Stage:
         t0 = time.perf_counter()
-        # the policy that *this* iteration executed — _genpolicy_step /
-        # _select_best may replace self.applied for the next one below
-        ran = self.applied
+        tracer = obs.tracer()
+        with tracer.span(obs.LANE_HOST, "runtime.end_iteration",
+                         arg=self.step_idx):
+            # the policy that *this* iteration executed — _genpolicy_step /
+            # _select_best may replace self.applied for the next one below
+            ran = self.applied
+            with tracer.span(obs.LANE_HOST, "runtime.signature"):
+                stage, prev_stage, shape_drift = self._observe_iteration(
+                    t_iter)
+
+            # episodic adaptation work (Detailed profiling, variant
+            # selection, policystore write/lookup, re-prepare) is accounted
+            # separately from the steady-state Lightweight-mode
+            # bookkeeping: the paper's Table-1 overhead claim is
+            # per-iteration, adaptation is what benchmarks/adapt_bench.py
+            # measures
+            t_adapt = time.perf_counter()
+            with tracer.span(obs.LANE_HOST, "runtime.adapt"):
+                self._stage_work(stage, prev_stage, shape_drift, t_iter)
+            adapt_dt = time.perf_counter() - t_adapt
+            self.adaptation_overhead_s += adapt_dt
+            # §5.4.2 execution feedback for the policy that just ran:
+            # mirror its swap schedule through the engine (real
+            # policy_swap-class copies, released by advance_op at each
+            # promised op), then sweep any remaining planned swap-outs —
+            # the iteration's op stream has fully executed, so every
+            # promised release point has passed — and reset the op cursor
+            # for the next iteration.
+            if self.hostmem is not None and ran.release_plan:
+                with tracer.span(obs.LANE_HOST, "runtime.mirror"):
+                    self._mirror_policy_swaps(ran)
+                    eng = self.hostmem.engine
+                    eng.advance_op(max(ran.release_plan.values()))
+                    eng.begin_iteration()
+            # async swap-in point: only *after* the executed policy's
+            # engine feedback drained may a worker result replace
+            # self.applied — the iteration boundary the swap-in protocol
+            # promises
+            if self.machine.stage is Stage.ADAPTING:
+                t_install = time.perf_counter()
+                with tracer.span(obs.LANE_HOST, "runtime.install"):
+                    self._poll_adaptation()
+                self.adaptation_overhead_s += time.perf_counter() - t_install
+            # degradation ladder (repro.faults): react to link health after
+            # this iteration's engine feedback; GenPolicy iterations are
+            # skipped — the variant search overwrites self.applied anyway
+            # and _select_best's install resets the ladder
+            if self.ladder is not None and stage is not Stage.GENPOLICY:
+                t_ladder = time.perf_counter()
+                with tracer.span(obs.LANE_HOST, "runtime.ladder"):
+                    self._ladder_step()
+                self.adaptation_overhead_s += time.perf_counter() - t_ladder
+            self.history.append({"step": self.step_idx, "stage": stage.value,
+                                 "policy": self.applied.fingerprint,
+                                 "t_iter": t_iter})
+            with tracer.span(obs.LANE_HOST, "runtime.obs_close"):
+                self._close_obs_window(ran)
+        self.profiling_overhead_s += (time.perf_counter() - t0) - adapt_dt
+        return stage
+
+    def _observe_iteration(self, t_iter: float
+                           ) -> Tuple[Stage, Stage, bool]:
+        """Fold the iteration's dispatches into its signature and step the
+        stage machine; returns (stage, previous stage, shape drift)."""
         sig = self._sig_acc.update(self._iter_streams)
         self._iter_streams = []
         self._last_sig = sig
@@ -366,13 +438,11 @@ class ChameleonRuntime:
         if self._pending_variant is not None:
             self._pending_variant.measured_t = t_iter
             self._pending_variant = None
+        return stage, prev_stage, shape_drift
 
-        # episodic adaptation work (Detailed profiling, variant selection,
-        # policystore write/lookup, re-prepare) is accounted separately
-        # from the steady-state Lightweight-mode bookkeeping: the paper's
-        # Table-1 overhead claim is per-iteration, adaptation is what
-        # benchmarks/adapt_bench.py measures
-        t_adapt = time.perf_counter()
+    def _stage_work(self, stage: Stage, prev_stage: Stage,
+                    shape_drift: bool, t_iter: float) -> None:
+        """The adaptation work the stage transition asks for."""
         if stage is Stage.GENPOLICY:
             self._genpolicy_step(t_iter)
         elif stage is Stage.STABLE and prev_stage is Stage.GENPOLICY:
@@ -403,51 +473,21 @@ class ChameleonRuntime:
                     self._jaxpr_cache.clear()
                     self._baseprof_cache.clear()
                 self.prepare(args)
-        adapt_dt = time.perf_counter() - t_adapt
-        self.adaptation_overhead_s += adapt_dt
-        # §5.4.2 execution feedback for the policy that just ran: mirror
-        # its swap schedule through the engine (real policy_swap-class
-        # copies, released by advance_op at each promised op), then sweep
-        # any remaining planned swap-outs — the iteration's op stream has
-        # fully executed, so every promised release point has passed —
-        # and reset the op cursor for the next iteration.
-        if self.hostmem is not None and ran.release_plan:
-            self._mirror_policy_swaps(ran)
-            eng = self.hostmem.engine
-            eng.advance_op(max(ran.release_plan.values()))
-            eng.begin_iteration()
-        # async swap-in point: only *after* the executed policy's engine
-        # feedback drained may a worker result replace self.applied — the
-        # iteration boundary the swap-in protocol promises
-        if self.machine.stage is Stage.ADAPTING:
-            t_install = time.perf_counter()
-            res = self.service.poll()
-            if res is not None:
-                self._install_result(res, "adapt-installed")
-            elif self.service.watchdog(self.cfg.resilience.adapt_timeout_s):
-                # hung or lost worker: supersede its epoch (a late result
-                # can never install) and un-wedge the stage machine — the
-                # current policy keeps serving, which is safe by
-                # construction (it fit before the drift)
-                self.service.invalidate("worker-timeout")
-                self.machine.complete_adapting(self.step_idx,
-                                               "adapt-timeout")
-                self._finish_adaptation("timeout")
-            self.adaptation_overhead_s += time.perf_counter() - t_install
-        # degradation ladder (repro.faults): react to link health after
-        # this iteration's engine feedback; GenPolicy iterations are
-        # skipped — the variant search overwrites self.applied anyway and
-        # _select_best's install resets the ladder
-        if self.ladder is not None and stage is not Stage.GENPOLICY:
-            t_ladder = time.perf_counter()
-            self._ladder_step()
-            self.adaptation_overhead_s += time.perf_counter() - t_ladder
-        self.history.append({"step": self.step_idx, "stage": stage.value,
-                             "policy": self.applied.fingerprint,
-                             "t_iter": t_iter})
-        self._close_obs_window(ran)
-        self.profiling_overhead_s += (time.perf_counter() - t0) - adapt_dt
-        return stage
+
+    def _poll_adaptation(self) -> None:
+        """Install a finished background adaptation, or trip the watchdog
+        on a hung one."""
+        res = self.service.poll()
+        if res is not None:
+            self._install_result(res, "adapt-installed")
+        elif self.service.watchdog(self.cfg.resilience.adapt_timeout_s):
+            # hung or lost worker: supersede its epoch (a late result can
+            # never install) and un-wedge the stage machine — the current
+            # policy keeps serving, which is safe by construction (it fit
+            # before the drift)
+            self.service.invalidate("worker-timeout")
+            self.machine.complete_adapting(self.step_idx, "adapt-timeout")
+            self._finish_adaptation("timeout")
 
     def _close_obs_window(self, ran: Optional[AppliedPolicy] = None) -> None:
         """Per-iteration overlap efficiency: how much of this window's
@@ -619,7 +659,7 @@ class ChameleonRuntime:
                 applied = self.executor.conservative(None)
         if applied is None:              # RUNG_NO_SWAP (or nothing else)
             applied = self.executor.baseline()
-        self.applied = applied
+        self._install(applied, "ladder")
         self.executor.bind_release_points(applied, self.hostmem.engine)
         self.hostmem.engine.begin_iteration()
         obs.audit().event(
@@ -652,7 +692,7 @@ class ChameleonRuntime:
                                     engine=hm.engine if hm else None)
         self.variants.append(var)
         self._pending_variant = var
-        self.applied = var.applied                 # next iteration runs it
+        self._install(var.applied, "genpolicy")    # next iteration runs it
 
     def _select_best(self) -> None:
         with obs.tracer().span(obs.LANE_ADAPT, "select_best",
@@ -672,7 +712,7 @@ class ChameleonRuntime:
 
     def _select_best_timed(self, timed: List[PolicyVariant]) -> None:
         self.best = min(timed, key=lambda v: v.measured_t)
-        self.applied = self.best.applied
+        self._install(self.best.applied, "select_best")
         if self.hostmem is not None and self.best.swap is not None:
             # §5.4.2 hand-off: only the applied policy's release points
             # reach the engine; end_iteration drives engine.advance_op
@@ -729,7 +769,7 @@ class ChameleonRuntime:
         adaptation at the iteration boundary.  Mirrors the inline
         ``_select_best_timed`` install — applied policy, engine release
         points, stage transition, accounting."""
-        self.applied = res.applied
+        self._install(res.applied, why)
         if res.profile is not None:
             self.profile = res.profile
             if res.iter_exact:           # recurrences skip worker profiling

@@ -18,6 +18,7 @@ import contextlib
 import threading
 from typing import Tuple
 
+import jax
 from jax.ad_checkpoint import checkpoint_name
 
 # The canonical site vocabulary.  Order matters: it is also the one-hot bit
@@ -69,8 +70,12 @@ def site_prefix(prefix: str):
 
 
 def tag(x, site: str):
+    """Name ``x`` as an offloadable site.  The ``name`` equation sits in
+    the named scope ``offload.<site>``; it lowers to no HLO op, and JAX
+    gives the offload copies it derives a source info of their own."""
     assert site in SITE_INDEX, f"unknown site {site!r}"
-    return checkpoint_name(x, _CTX.prefix + site)
+    with jax.named_scope(f"offload.{site}"):
+        return checkpoint_name(x, _CTX.prefix + site)
 
 
 def base_site(name: str) -> str:

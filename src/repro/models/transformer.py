@@ -106,17 +106,20 @@ def dense_block(cfg: ModelConfig, p, x, positions, cross_kv=None,
     aux = jnp.zeros((), jnp.float32)
     x = tag(x, "ln_in")
     h = L.apply_norm(cfg, p["ln1"], x)
-    x = x + attn.self_attention(cfg, p["attn"], h, positions, causal=causal)
+    with jax.named_scope("attention"):
+        x = x + attn.self_attention(cfg, p["attn"], h, positions,
+                                    causal=causal)
     x = tag(x, "resid_mid")
     if cross_kv is not None and "xattn" in p:
         h = L.apply_norm(cfg, p["lnx"], x)
         xa = attn.cross_attention(cfg, p["xattn"], h, cross_kv)
         x = x + jnp.tanh(p["xgate"]).astype(x.dtype) * xa
     h = L.apply_norm(cfg, p["ln2"], x)
-    if "moe" in p:
-        out, aux = moe_lib.apply_moe_auto(cfg, p["moe"], h)
-    else:
-        out = L.apply_mlp(cfg, p["mlp"], h)
+    with jax.named_scope("ffn"):
+        if "moe" in p:
+            out, aux = moe_lib.apply_moe_auto(cfg, p["moe"], h)
+        else:
+            out = L.apply_mlp(cfg, p["mlp"], h)
     x = x + out
     return tag(x, "resid_post"), aux
 
@@ -234,7 +237,8 @@ def forward(cfg: ModelConfig, params, tokens, *, positions=None,
 def loss_fn(cfg: ModelConfig, params, batch, *, policy=None):
     logits, aux = forward(cfg, params, batch["tokens"], policy=policy,
                           memory=batch.get("memory"))
-    loss = L.cross_entropy(logits, batch["labels"], batch.get("mask"))
+    with jax.named_scope("loss"):
+        loss = L.cross_entropy(logits, batch["labels"], batch.get("mask"))
     return loss + aux, {"xent": loss, "aux": aux}
 
 

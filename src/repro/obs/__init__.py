@@ -4,9 +4,10 @@ Three pillars (ISSUE 6), all bounded-memory so they stay enabled in
 production, matching the monitoring hot path's "cheap enough to leave
 on" bar:
 
-  * :class:`SpanTracer` — ring-buffered span recorder over five fixed
-    lanes (``compute``, ``policy_swap``, ``kv_spill``, ``checkpoint``,
-    ``adapt``), exported as Chrome trace-event JSON
+  * :class:`SpanTracer` — ring-buffered span tree over six fixed lanes
+    (``compute``, ``policy_swap``, ``kv_spill``, ``checkpoint``,
+    ``adapt``, ``host``), mirrored onto the JAX profiler's host plane and
+    exported as Chrome trace-event JSON
     (:func:`export_chrome_trace`) and reduced to a per-iteration
     **overlap-efficiency** metric (:mod:`repro.obs.overlap`);
   * :class:`MetricsRegistry` — one counter/gauge/provider registry the
@@ -36,8 +37,9 @@ from repro.obs.metrics import MetricsRegistry, SNAPSHOT_KEYS
 from repro.obs.overlap import (interval_union, overlap_efficiency,
                                window_efficiency)
 from repro.obs.tracer import (LANE_ADAPT, LANE_CHECKPOINT, LANE_COMPUTE,
-                              LANE_ID, LANE_KV_SPILL, LANE_POLICY_SWAP,
-                              LANES, TRANSFER_LANES, SpanTracer,
+                              LANE_HOST, LANE_ID, LANE_KV_SPILL,
+                              LANE_POLICY_SWAP, LANES, TRANSFER_LANES,
+                              SpanTracer,
                               chrome_trace_events, export_chrome_trace)
 from repro.obs.validate import validate_chrome_trace, validate_metrics_jsonl
 
@@ -45,7 +47,7 @@ __all__ = [
     "AuditLog", "MetricsRegistry", "SpanTracer", "SNAPSHOT_KEYS",
     "MemoryLedger", "LEDGER_TRACKS",
     "LANES", "LANE_ID", "LANE_COMPUTE", "LANE_POLICY_SWAP", "LANE_KV_SPILL",
-    "LANE_CHECKPOINT", "LANE_ADAPT", "TRANSFER_LANES",
+    "LANE_CHECKPOINT", "LANE_ADAPT", "LANE_HOST", "TRANSFER_LANES",
     "chrome_trace_events", "export_chrome_trace",
     "interval_union", "overlap_efficiency", "window_efficiency",
     "validate_chrome_trace", "validate_metrics_jsonl",

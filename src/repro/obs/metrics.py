@@ -60,10 +60,6 @@ class MetricsRegistry:
                     maxlen=self.series_len)
             s.append((time.time() if t is None else t, float(value)))
 
-    def series(self, name: str) -> List[Tuple[float, float]]:
-        with self._lock:
-            return list(self._series.get(name, ()))
-
     # ----------------------------------------------------------- providers
     def register_provider(self, name: str, fn: Callable[[], dict]) -> None:
         """Attach (or replace — a re-built subsystem re-registers under
@@ -114,10 +110,3 @@ class MetricsRegistry:
             self._series.clear()
             self._providers.clear()
             self._seq = 0
-
-    def stats(self) -> dict:
-        with self._lock:
-            return {"counters": len(self._counters),
-                    "gauges": len(self._gauges),
-                    "providers": len(self._providers),
-                    "snapshots": self._seq}

@@ -19,9 +19,18 @@ production, in the same spirit as the monitoring hot path (ISSUE 5):
     normalizes to the earliest retained timestamp.
 
 Lanes are fixed: one per traffic class of the transfer engine plus
-``compute`` (step execution) and ``adapt`` (the profile→drift→adapt→
-apply machinery).  Fixed lanes keep the record a single uint8 and give
-the Chrome-trace export a stable thread layout.
+``compute`` (step execution), ``adapt`` (the profile→drift→adapt→apply
+machinery) and ``host`` (the trainer's and the runtime's host phases).
+Fixed lanes keep the record a single uint8 and give the Chrome-trace
+export a stable thread layout.
+
+Spans form a tree.  ``span()`` gives each span an id when it opens and
+keeps a stack of open spans per thread; every record stores its
+``parent`` (0 at a root), so a ``record()`` or ``instant()`` inside an
+open span hangs under it.  ``span()`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so whenever a profile
+is being taken the span lands on the profiler's host plane, on the
+device trace's clock (about 1 µs when none is).
 
 Export is Chrome trace-event JSON (``ph: "X"`` complete events plus
 ``ph: "C"`` counters), openable in Perfetto or ``chrome://tracing`` —
@@ -29,6 +38,7 @@ see :func:`export_chrome_trace`.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -36,15 +46,18 @@ from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-# Fixed lane set: engine traffic classes + compute + adaptation machinery.
+# Fixed lane set: engine traffic classes + compute + adaptation machinery
+# + the trainer's and runtime's host phases.
 LANE_COMPUTE = "compute"
 LANE_POLICY_SWAP = "policy_swap"
 LANE_KV_SPILL = "kv_spill"
 LANE_CHECKPOINT = "checkpoint"
 LANE_ADAPT = "adapt"
+LANE_HOST = "host"
 LANES: Tuple[str, ...] = (LANE_COMPUTE, LANE_POLICY_SWAP, LANE_KV_SPILL,
-                          LANE_CHECKPOINT, LANE_ADAPT)
+                          LANE_CHECKPOINT, LANE_ADAPT, LANE_HOST)
 LANE_ID: Dict[str, int] = {name: i for i, name in enumerate(LANES)}
 
 # transfer lanes considered "hideable under compute" by the overlap metric
@@ -71,11 +84,16 @@ class SpanTracer:
         self._t0 = np.zeros(self.capacity, np.float64)
         self._t1 = np.zeros(self.capacity, np.float64)
         self._iter = np.full(self.capacity, -1, np.int64)
+        self._id = np.zeros(self.capacity, np.int64)
+        self._parent = np.zeros(self.capacity, np.int64)
         self._arg: List[Any] = [None] * self.capacity
         self._names: Dict[str, int] = {}
         self._name_list: List[str] = []
         self._n = 0                      # total records ever (monotonic)
         self._lock = threading.Lock()
+        self._ids = itertools.count(1)   # never reused
+        self._dropped_id = 0             # largest id the ring overwrote
+        self._open = threading.local()   # per-thread stack of open span ids
         self.current_iter = -1           # stamped onto every record
         self.enabled = True
 
@@ -98,51 +116,72 @@ class SpanTracer:
         return nid
 
     # ------------------------------------------------------------ recording
-    def record(self, lane: str, name: str, t0: float, t1: float,
-               arg: Any = None) -> None:
-        """Record one completed span.  ``t0``/``t1`` are perf_counter
-        readings taken by the caller (so the record call itself is not
-        inside the measured interval)."""
-        if not self.enabled:
-            return
+    def _stack(self) -> List[int]:
+        stack = getattr(self._open, "ids", None)
+        if stack is None:
+            stack = self._open.ids = []
+        return stack
+
+    def _write(self, lane: str, kind: int, name: str, t0: float, t1: float,
+               it: int, arg: Any, sid: int, parent: int) -> None:
         lid = LANE_ID[lane]
         with self._lock:
             i = self._n % self.capacity
+            if self._n >= self.capacity:
+                self._dropped_id = max(self._dropped_id, int(self._id[i]))
             self._lane[i] = lid
-            self._kind[i] = _KIND_SPAN
+            self._kind[i] = kind
             self._name[i] = self._name_id(name)
             self._t0[i] = t0
             self._t1[i] = t1
-            self._iter[i] = self.current_iter
+            self._iter[i] = it
+            self._id[i] = sid
+            self._parent[i] = parent
             self._arg[i] = arg
             self._n += 1
+
+    def _leaf(self, lane: str, kind: int, name: str, t0: float, t1: float,
+              arg: Any) -> None:
+        stack = self._stack()
+        self._write(lane, kind, name, t0, t1, self.current_iter, arg,
+                    next(self._ids), stack[-1] if stack else 0)
+
+    def record(self, lane: str, name: str, t0: float, t1: float,
+               arg: Any = None) -> None:
+        """Record one completed span, under the calling thread's open span.
+        ``t0``/``t1`` are perf_counter readings taken by the caller (so the
+        record call itself is not inside the measured interval)."""
+        if self.enabled:
+            self._leaf(lane, _KIND_SPAN, name, t0, t1, arg)
 
     def instant(self, lane: str, name: str, t: Optional[float] = None,
                 arg: Any = None) -> None:
         """Record a zero-duration marker (Chrome ``ph: "i"``)."""
-        if not self.enabled:
-            return
-        ts = time.perf_counter() if t is None else t
-        lid = LANE_ID[lane]
-        with self._lock:
-            i = self._n % self.capacity
-            self._lane[i] = lid
-            self._kind[i] = _KIND_INSTANT
-            self._name[i] = self._name_id(name)
-            self._t0[i] = ts
-            self._t1[i] = ts
-            self._iter[i] = self.current_iter
-            self._arg[i] = arg
-            self._n += 1
+        if self.enabled:
+            ts = time.perf_counter() if t is None else t
+            self._leaf(lane, _KIND_INSTANT, name, ts, ts, arg)
 
     @contextmanager
     def span(self, lane: str, name: str, arg: Any = None):
-        """Context manager form; records on exit (exceptions included)."""
+        """Context manager form: the span opens as the calling thread's
+        innermost, under a profiler annotation of the same name, and is
+        recorded on exit (exceptions included).  The iteration stamp is
+        the one current when it opens."""
+        if not self.enabled:
+            yield
+            return
+        stack = self._stack()
+        sid, parent, it = (next(self._ids), stack[-1] if stack else 0,
+                           self.current_iter)
+        stack.append(sid)
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
-            self.record(lane, name, t0, time.perf_counter(), arg)
+            t1 = time.perf_counter()
+            stack.pop()
+            self._write(lane, _KIND_SPAN, name, t0, t1, it, arg, sid, parent)
 
     def set_iteration(self, it: int) -> None:
         self.current_iter = int(it)
@@ -158,13 +197,12 @@ class SpanTracer:
                                np.arange(0, head)])
 
     def spans(self, lanes: Optional[Sequence[str]] = None,
-              it: Optional[int] = None,
-              kinds: Tuple[int, ...] = (_KIND_SPAN,)) -> np.ndarray:
+              it: Optional[int] = None) -> np.ndarray:
         """Retained spans as an ``(n, 2)`` float array of (t0, t1),
         optionally filtered by lane set and iteration stamp."""
         with self._lock:
             idx = self._valid()
-            mask = np.isin(self._kind[idx], list(kinds))
+            mask = self._kind[idx] == _KIND_SPAN
             if lanes is not None:
                 lids = [LANE_ID[l] for l in lanes]
                 mask &= np.isin(self._lane[idx], lids)
@@ -186,6 +224,8 @@ class SpanTracer:
                     "t0": float(self._t0[i]),
                     "t1": float(self._t1[i]),
                     "iter": int(self._iter[i]),
+                    "id": int(self._id[i]),
+                    "parent": int(self._parent[i]),
                     "arg": self._arg[i],
                 })
             return out
@@ -206,6 +246,8 @@ class SpanTracer:
                 "n_spans": self._n,
                 "retained": min(self._n, self.capacity),
                 "dropped": max(self._n - self.capacity, 0),
+                # no record with a larger id has been dropped
+                "dropped_id": self._dropped_id,
                 "capacity": self.capacity,
                 "names": len(self._name_list),
             }
@@ -245,7 +287,7 @@ def chrome_trace_events(tracer: SpanTracer,
     for r in recs:
         tid = LANE_ID[r["lane"]]
         ts = (r["t0"] - t_min) * 1e6
-        args = {"iter": r["iter"]}
+        args = {"iter": r["iter"], "id": r["id"], "parent": r["parent"]}
         if r["arg"] is not None:
             args["detail"] = _json_safe(r["arg"])
         if r["kind"] == "span":

@@ -193,82 +193,99 @@ class Trainer:
     def train(self, steps: Optional[int] = None,
               fault_hook: Optional[Callable[[int], None]] = None
               ) -> TrainReport:
-        steps = steps if steps is not None else self.tcfg.steps
-        batch = self._device_batch(self.data.get())
-        if not self._prepared:
-            self.rt.prepare((self.params, batch, self.loss_scale.scale))
-            self._prepared = True
-        end = self.step + steps
-        while self.step < end:
-            try:
-                self._one_step(batch, fault_hook)
+        """Train ``steps`` iterations (default ``tcfg.steps``) under one
+        ``trainer.train`` span, the root of their host-span tree."""
+        tracer = obs.tracer()
+        with tracer.span(obs.LANE_HOST, "trainer.train", arg=steps):
+            steps = steps if steps is not None else self.tcfg.steps
+            with tracer.span(obs.LANE_HOST, "trainer.data"):
                 batch = self._device_batch(self.data.get())
-            except (KeyboardInterrupt, Exception) as e:  # noqa: BLE001
-                self.report.failures.append(f"step {self.step}: {e!r}")
+            if not self._prepared:
+                self.rt.prepare((self.params, batch, self.loss_scale.scale))
+                self._prepared = True
+            end = self.step + steps
+            while self.step < end:
+                try:
+                    self._one_step(batch, fault_hook)
+                    with tracer.span(obs.LANE_HOST, "trainer.data"):
+                        batch = self._device_batch(self.data.get())
+                except (KeyboardInterrupt, Exception) as e:  # noqa: BLE001
+                    self.report.failures.append(f"step {self.step}: {e!r}")
+                    self.ckpt.wait()
+                    self._checkpoint(block=True)   # emergency checkpoint
+                    raise
+            with tracer.span(obs.LANE_HOST, "trainer.finish"):
                 self.ckpt.wait()
-                self._checkpoint(block=True)   # emergency checkpoint
-                raise
-        self.ckpt.wait()
-        self.report.policystore = self.rt.policystore_stats()
-        self.report.adapt = self.rt.service.stats()
+                self.report.policystore = self.rt.policystore_stats()
+                self.report.adapt = self.rt.service.stats()
         return self.report
 
     def _one_step(self, batch, fault_hook=None):
+        tracer = obs.tracer()
         faults.tick(self.step)   # armed fault plans key off the iteration
         t0 = time.perf_counter()
         fn = self.rt.step_fn()
-        with obs.tracer().span(obs.LANE_COMPUTE, "train_step",
-                               arg=self.step):
-            loss, grads, finite = fn(self.params, batch,
-                                     self.loss_scale.scale)
-            jax.block_until_ready(loss)
+        with tracer.span(obs.LANE_COMPUTE, "train_step", arg=self.step):
+            with tracer.span(obs.LANE_HOST, "trainer.grad_dispatch"):
+                loss, grads, finite = fn(self.params, batch,
+                                         self.loss_scale.scale)
+            with tracer.span(obs.LANE_HOST, "trainer.grad_wait"):
+                jax.block_until_ready(loss)
         self.rt.record_dispatch("train", fn,
                                 (self.params, batch, self.loss_scale.scale))
-        finite_h = bool(finite)
+        # the optimizer step does not read the loss scale, so the scale's
+        # update may run before it
+        with tracer.span(obs.LANE_HOST, "trainer.loss_scale"):
+            finite_h = bool(finite)
+            self.loss_scale = update_loss_scale(self.loss_scale, finite_h)
         if finite_h:
-            with obs.tracer().span(obs.LANE_COMPUTE, "apply_step",
-                                   arg=self.step):
-                self.params, self.opt_state, _m = self._apply(
-                    self.params, self.opt_state, grads)
-                jax.block_until_ready(self.params)
+            with tracer.span(obs.LANE_COMPUTE, "apply_step", arg=self.step):
+                with tracer.span(obs.LANE_HOST, "trainer.apply_dispatch"):
+                    self.params, self.opt_state, _m = self._apply(
+                        self.params, self.opt_state, grads)
+                with tracer.span(obs.LANE_HOST, "trainer.apply_wait"):
+                    jax.block_until_ready(self.params)
             self.rt.record_dispatch("apply", self._apply,
                                     (self.params, self.opt_state, grads))
         else:
             self.report.skipped_steps.append(self.step)
-        self.loss_scale = update_loss_scale(self.loss_scale, finite_h)
 
         if (self.tcfg.eval_every
                 and self.step > 0
                 and self.step % self.tcfg.eval_every == 0):
-            ebatch = self._device_batch(self.eval_data.next_batch())
-            with obs.tracer().span(obs.LANE_COMPUTE, "eval_step",
-                                   arg=self.step):
+            with tracer.span(obs.LANE_HOST, "trainer.data"):
+                ebatch = self._device_batch(self.eval_data.next_batch())
+            with tracer.span(obs.LANE_COMPUTE, "eval_step", arg=self.step):
                 el = self._eval(self.params, ebatch)
-                jax.block_until_ready(el)
+                with tracer.span(obs.LANE_HOST, "trainer.eval_wait"):
+                    jax.block_until_ready(el)
             self.rt.record_dispatch("eval", self._eval, (self.params, ebatch))
             self.report.eval_losses[self.step] = float(el)
 
         dt = time.perf_counter() - t0
         stage = self.rt.end_iteration(dt)
-        # flag on the full critical-path latency (compute + end_iteration
-        # bookkeeping): a degraded host link or a drift stall shows up in
-        # the wall time even when the jitted step itself is healthy
-        wall = time.perf_counter() - t0
-        self.straggler.observe(self.step, wall)
-        self.report.losses.append(float(loss))
-        self.report.times.append(dt)
-        self.report.wall_times.append(wall)
-        self.report.stages.append(stage.value)
-        self.step += 1
-        # step is incremented BEFORE any failure can be raised for this
-        # iteration: the emergency checkpoint then records post-step state
-        # under step N+1 and resume does not replay an applied update.
-        if fault_hook is not None:
-            fault_hook(self.step - 1)
+        with tracer.span(obs.LANE_HOST, "trainer.finish"):
+            # flag on the full critical-path latency (compute +
+            # end_iteration bookkeeping): a degraded host link or a drift
+            # stall shows up in the wall time even when the jitted step
+            # itself is healthy
+            wall = time.perf_counter() - t0
+            self.straggler.observe(self.step, wall)
+            self.report.losses.append(float(loss))
+            self.report.times.append(dt)
+            self.report.wall_times.append(wall)
+            self.report.stages.append(stage.value)
+            self.step += 1
+            # step is incremented BEFORE any failure can be raised for this
+            # iteration: the emergency checkpoint then records post-step
+            # state under step N+1 and resume does not replay an applied
+            # update.
+            if fault_hook is not None:
+                fault_hook(self.step - 1)
 
-        if (self.tcfg.checkpoint_every
-                and self.step % self.tcfg.checkpoint_every == 0):
-            self._checkpoint()
+            if (self.tcfg.checkpoint_every
+                    and self.step % self.tcfg.checkpoint_every == 0):
+                self._checkpoint()
 
-        if self.metrics_out and self.step % self.metrics_every == 0:
-            obs.metrics().write_jsonl(self.metrics_out)
+            if self.metrics_out and self.step % self.metrics_every == 0:
+                obs.metrics().write_jsonl(self.metrics_out)

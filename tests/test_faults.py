@@ -377,6 +377,40 @@ def test_ladder_reset_and_probe_throttle():
     assert any(t["why"] == "new-policy" for t in lad.transitions)
 
 
+def test_ladder_move_records_a_policy_install():
+    """A rung the ladder moves to is a new applied policy: one
+    ``policy.install`` instant (arg ``ladder``) under ``runtime.ladder``,
+    counted in ``policy_installs``."""
+    from repro.common.config import ChameleonConfig
+    from repro.core.executor import AppliedPolicy
+    from repro.core.runtime import ChameleonRuntime
+    old_t = obs.set_tracer(obs.SpanTracer(capacity=256))
+    old_m = obs.set_metrics(obs.MetricsRegistry())
+    try:
+        rt = ChameleonRuntime(ChameleonConfig(), lambda pol: (lambda *a: a))
+        full = AppliedPolicy(None, {"ffn_act"}, set(), set(), "off=ffn_act")
+        rt.applied = rt._full_applied = full
+        health = rt.hostmem.engine.health
+        while health.worst() != FAILED:
+            health.note_error(TC_POLICY_SWAP)
+        rt.end_iteration(0.01)
+        assert rt.ladder.rung == RUNG_TRIMMED
+        assert rt.applied.fingerprint != full.fingerprint
+        by = {r["id"]: r for r in obs.tracer().records()}
+        installs = [r for r in by.values() if r["name"] == "policy.install"]
+        assert [(r["lane"], r["kind"], r["arg"]) for r in installs] == [
+            (obs.LANE_ADAPT, "instant", "ladder")]
+        ladder = by[installs[0]["parent"]]
+        assert ladder["name"] == "runtime.ladder"
+        assert by[ladder["parent"]]["name"] == "runtime.end_iteration"
+        assert obs.metrics().snapshot()["counters"]["policy_installs"] == 1
+        rt.end_iteration(0.01)               # hold: no move, no install
+        assert obs.metrics().snapshot()["counters"]["policy_installs"] == 1
+    finally:
+        obs.set_tracer(old_t)
+        obs.set_metrics(old_m)
+
+
 def test_trim_swap_drops_lowest_scores_within_budget(monkeypatch):
     entries = [SimpleNamespace(uid=i, score=float(i), nbytes=10)
                for i in range(10)]

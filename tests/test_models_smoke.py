@@ -121,3 +121,40 @@ def test_cell_matrix_skips():
     assert "long_500k" in m["mamba2_780m"]
     assert "long_500k" in m["zamba2_1_2b"]
     assert "long_500k" not in m["qwen2_7b"]
+
+
+def test_grad_step_carries_named_scopes():
+    """Attention, FFN and loss are named scopes in the lowered grad step's
+    debug text; every offload site's ``name`` equation sits in the scope
+    ``offload.<site>``."""
+    import re
+    from repro.core.executor import jax_offload_policy
+    from repro.distributed.steps import abstract_params, make_grad_step
+    cfg = C.get_reduced("llama2_paper")
+    step = make_grad_step(cfg, TrainConfig(),
+                          jax_offload_policy(["ffn_act", "ln_in"], []))
+    batch = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
+             for k in ("tokens", "labels")}
+    args = (abstract_params(cfg), batch, jax.ShapeDtypeStruct((), jnp.float32))
+    text = jax.jit(step).lower(*args).as_text(debug_info=True)
+    scopes = set()
+    for loc in re.findall(r'loc\("([^"]*)"', text):
+        scopes.update(re.split(r"[/()]", loc))
+    assert {"attention", "ffn", "loss"} <= scopes
+
+    named = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "name":
+                named.append((eqn.params["name"],
+                              str(eqn.source_info.name_stack)))
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(step)(*args).jaxpr)
+    assert {n for n, _ in named} >= {"ffn_act", "ln_in", "attn_out"}
+    assert all(f"offload.{n}" in re.split(r"[/()]", stack)
+               for n, stack in named)

@@ -107,6 +107,89 @@ def test_tracer_disabled_records_nothing():
     assert tr.stats()["n_spans"] == 0
 
 
+def test_tracer_records_id_and_parent():
+    tr = SpanTracer(capacity=64)
+    tr.set_iteration(5)
+    with tr.span(obs.LANE_HOST, "root"):
+        tr.set_iteration(6)              # the stamp is taken at open
+        with tr.span(obs.LANE_HOST, "child"):
+            tr.record(obs.LANE_POLICY_SWAP, "copy", 0.0, 1.0)
+            tr.instant(obs.LANE_ADAPT, "mark")
+        tr.instant(obs.LANE_ADAPT, "after-child")
+    tr.record(obs.LANE_COMPUTE, "loose", 2.0, 3.0)
+    by = {r["name"]: r for r in tr.records()}
+    root, child = by["root"], by["child"]
+    assert root["parent"] == 0 and root["iter"] == 5
+    assert child["parent"] == root["id"] and child["iter"] == 6
+    assert by["copy"]["parent"] == by["mark"]["parent"] == child["id"]
+    assert by["after-child"]["parent"] == root["id"]
+    assert by["loose"]["parent"] == 0
+    assert len({r["id"] for r in by.values()}) == len(by)
+    assert root["t0"] <= child["t0"] <= child["t1"] <= root["t1"]
+
+
+def test_tracer_nesting_is_per_thread():
+    """A span opened on another thread while this one has a span open
+    does not become its child; each thread keeps its own stack."""
+    import threading
+    tr = SpanTracer(capacity=256)
+    opened, release = threading.Event(), threading.Event()
+
+    def worker():
+        with tr.span(obs.LANE_CHECKPOINT, "w.outer"):
+            opened.set()
+            release.wait(10)
+            with tr.span(obs.LANE_CHECKPOINT, "w.inner"):
+                tr.record(obs.LANE_CHECKPOINT, "w.copy", 0.0, 1.0)
+
+    with tr.span(obs.LANE_HOST, "main.outer"):
+        t = threading.Thread(target=worker)
+        t.start()
+        assert opened.wait(10)
+        with tr.span(obs.LANE_HOST, "main.inner"):
+            release.set()
+            t.join(10)
+    assert not t.is_alive()
+    by = {r["name"]: r for r in tr.records()}
+    assert by["w.outer"]["parent"] == by["main.outer"]["parent"] == 0
+    assert by["w.inner"]["parent"] == by["w.outer"]["id"]
+    assert by["w.copy"]["parent"] == by["w.inner"]["id"]
+    assert by["main.inner"]["parent"] == by["main.outer"]["id"]
+
+
+def test_tracer_tracks_the_largest_dropped_id():
+    tr = SpanTracer(capacity=16)
+    for i in range(16):
+        tr.record(obs.LANE_COMPUTE, "s", 0.0, 1.0)
+    assert tr.stats()["dropped_id"] == 0
+    for i in range(3):
+        tr.record(obs.LANE_COMPUTE, "s", 0.0, 1.0)
+    ids = [r["id"] for r in tr.records()]
+    assert tr.stats()["dropped_id"] == 3 and min(ids) == 4
+
+
+def test_tracer_span_lands_on_the_profilers_host_plane(tmp_path):
+    """``span()`` writes a TraceAnnotation: a profile taken around it
+    carries the span's name on a ``/host:`` plane."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    tr = SpanTracer(capacity=64)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span(obs.LANE_HOST, "unit.profiled_span"):
+            jax.numpy.ones(3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(paths) == 1
+    planes = ProfileData.from_file(paths[0]).planes
+    host = [ev.name for p in planes if p.name.startswith("/host:")
+            for line in p.lines for ev in line.events]
+    assert "unit.profiled_span" in host
+    assert tr.records()[0]["name"] == "unit.profiled_span"
+
+
 def test_tracer_record_wall_clock_budget():
     """Generous always-on ceiling: recording must stay in the microsecond
     range (CI-tolerant bound — the deterministic boundedness guards above
@@ -260,6 +343,9 @@ def test_chrome_export_roundtrips_through_validator(tmp_path):
     assert all(e["ts"] >= 0 for e in xs)
     assert all(e["args"]["iter"] == 1 for e in xs)
     assert xs[0]["args"]["detail"] == ["tag", 123]
+    # the span tree survives the export
+    assert [e["args"]["id"] for e in xs] == list(range(1, len(xs) + 1))
+    assert all(e["args"]["parent"] == 0 for e in xs)
 
 
 def test_validator_rejects_missing_lane():

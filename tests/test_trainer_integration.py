@@ -153,6 +153,81 @@ def test_server_matches_single_request():
     assert len(out2[rb]) == 4
 
 
+TRAIN_TREE = {
+    # span: its parent in the tree of one Trainer.train(1) call
+    "trainer.data": "trainer.train",
+    "train_step": "trainer.train",
+    "trainer.grad_dispatch": "train_step",
+    "trainer.grad_wait": "train_step",
+    "runtime.record_dispatch": "trainer.train",
+    "trainer.loss_scale": "trainer.train",
+    "apply_step": "trainer.train",
+    "trainer.apply_dispatch": "apply_step",
+    "trainer.apply_wait": "apply_step",
+    "runtime.end_iteration": "trainer.train",
+    "runtime.signature": "runtime.end_iteration",
+    "runtime.adapt": "runtime.end_iteration",
+    "runtime.ladder": "runtime.end_iteration",
+    "runtime.obs_close": "runtime.end_iteration",
+    "trainer.finish": "trainer.train",
+}
+
+
+def _call_trees(recs):
+    """The records of each trainer.train call, checked: every record
+    reaches a root through parents whose intervals hold it."""
+    by = {r["id"]: r for r in recs}
+    calls = {r["id"]: [] for r in recs if r["name"] == "trainer.train"}
+    for r in recs:
+        node = r
+        while node["name"] != "trainer.train":
+            parent = by[node["parent"]]
+            assert parent["t0"] <= node["t0"] <= node["t1"] <= parent["t1"], \
+                (node, parent)
+            node = parent
+        calls[node["id"]].append(r)
+    return by, list(calls.values())
+
+
+def test_train_call_is_one_span_tree(tmpdir):
+    """One Trainer.train(1) records the host-span tree under its
+    trainer.train root; runtime.mirror joins it when the applied policy
+    has a release plan."""
+    from repro import obs
+    from repro.core.executor import Executor
+    from repro.core.memtrace import build_timeline
+    from repro.core.policy import generate_policy
+    old = obs.set_tracer(obs.SpanTracer(capacity=1 << 13))
+    try:
+        tr = _trainer(tmpdir, cham=True, steps=4)
+        tr.train(1)
+        prof = tr.rt.baseline_profile
+        pol = generate_policy(prof, tr.cham,
+                              int(build_timeline(prof).peak * 0.85))
+        applied = Executor(tr.cham).lower(pol, prof)
+        assert applied.release_plan
+        tr.rt.applied = applied
+        tr.rt.executor.bind_release_points(applied, tr.rt.hostmem.engine)
+        tr.train(1)
+        by, calls = _call_trees(obs.tracer().records())
+    finally:
+        obs.set_tracer(old)
+    assert len(calls) == 2
+    for k, call in enumerate(calls):
+        names = {r["name"] for r in call}
+        assert set(TRAIN_TREE) <= names
+        for r in call:
+            if r["name"] in TRAIN_TREE:
+                assert by[r["parent"]]["name"] == TRAIN_TREE[r["name"]]
+        root = next(r for r in call if r["name"] == "trainer.train")
+        assert root["iter"] == k
+        assert ("runtime.mirror" in names) == (k == 1)
+    mirror = next(r for r in calls[1] if r["name"] == "runtime.mirror")
+    assert by[mirror["parent"]]["name"] == "runtime.end_iteration"
+    assert any(by[r["parent"]] is mirror for r in calls[1]
+               if r["lane"] == obs.LANE_POLICY_SWAP)
+
+
 def test_profiling_overhead_small(tmpdir):
     """Lightweight-mode bookkeeping must stay a small fraction of step time
     (paper Table 1: 0.9%).  CPU steps are ms-scale so allow generous 30%."""
