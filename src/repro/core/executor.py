@@ -8,6 +8,12 @@ simulator's swap-out completion points become buffer release points that the
 latency-hiding scheduler honors without host polling (§6.2); we additionally
 donate input buffers so optimizer-state memory is reused in place.
 
+An applied policy that offloads a site hands the model an
+:class:`OffloadSites`: a checkpoint policy that also names its two site
+sets, so the dense and MoE layer stacks can run the offload themselves,
+pipelined across layers (``repro.models.pipelined``, the paper's swap-in
+pre-trigger and deferred swap-out completion, §5.3).
+
 ``offload_mode="compressed"`` (beyond-paper, CSWAP-inspired) wraps offloaded
 sites in an int8 quantize/dequantize pair so swapped tensors cross the host
 link at half/quarter width — see ``repro.kernels.quant_offload``.
@@ -15,7 +21,7 @@ link at half/quarter width — see ``repro.kernels.quant_offload``.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Iterable, Optional, Sequence, Set
+from typing import Callable, Dict, FrozenSet, Iterable, Optional, Sequence, Set
 
 import jax
 
@@ -43,6 +49,24 @@ def jax_save_policy(save_sites: Iterable[str]):
         *sorted(set(save_sites)))
 
 
+@dataclass(frozen=True)
+class OffloadSites:
+    """What ``to_jax()`` hands the model for an applied policy that
+    offloads: sites in ``offload`` go to pinned host, sites in ``save``
+    stay in HBM, the rest are recomputed.  The dense and MoE stacks move
+    those residuals themselves (``repro.models.pipelined``); every other
+    stack applies ``checkpoint_policy``, ``jax_offload_policy`` of the two
+    sets."""
+    offload: FrozenSet[str]
+    save: FrozenSet[str]
+    checkpoint_policy: Callable = field(init=False, repr=False,
+                                        compare=False)
+
+    def __post_init__(self):
+        object.__setattr__(self, "checkpoint_policy",
+                           jax_offload_policy(self.offload, self.save))
+
+
 @dataclass
 class AppliedPolicy:
     swap: Optional[SwapPolicy]
@@ -56,12 +80,17 @@ class AppliedPolicy:
     # at the promised op (engine.advance_op) instead of at first reuse.
     release_plan: Dict[str, int] = field(default_factory=dict)
 
+    @property
+    def pipelined(self) -> bool:
+        """The stack is handed an :class:`OffloadSites` (it offloads)."""
+        return bool(self.offload) and not self.raw
+
     def to_jax(self):
         if self.raw:
             return None  # no checkpoint wrapper at all
-        if not self.offload:
-            return jax_save_policy(self.save)
-        return jax_offload_policy(self.offload, self.save)
+        if self.pipelined:
+            return OffloadSites(frozenset(self.offload), frozenset(self.save))
+        return jax_save_policy(self.save)
 
 
 class Executor:

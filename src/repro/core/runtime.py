@@ -234,10 +234,15 @@ class ChameleonRuntime:
     def _install(self, applied: AppliedPolicy, reason: str) -> None:
         """Make ``applied`` the policy the next iteration runs.  A change
         of fingerprint is a ``policy.install`` instant on the adapt lane,
-        with ``reason`` as its arg, and counts in ``policy_installs``."""
+        with ``(reason, pipelined)`` as its arg, and counts in
+        ``policy_installs`` (and in ``offload_pipelined_installs`` when the
+        layer stack is handed an offload set to pipeline)."""
         if applied.fingerprint != self.applied.fingerprint:
-            obs.tracer().instant(obs.LANE_ADAPT, "policy.install", arg=reason)
+            obs.tracer().instant(obs.LANE_ADAPT, "policy.install",
+                                 arg=(reason, applied.pipelined))
             obs.metrics().counter("policy_installs")
+            if applied.pipelined:
+                obs.metrics().counter("offload_pipelined_installs")
         self.applied = applied
 
     def _audit_apply(self, kind: str, knob: Optional[float] = None) -> None:

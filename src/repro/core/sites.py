@@ -53,6 +53,7 @@ SITE_INDEX = {s: i for i, s in enumerate(OFFLOAD_SITES)}
 class _Ctx(threading.local):
     def __init__(self):
         self.prefix = ""
+        self.hook = None
 
 
 _CTX = _Ctx()
@@ -69,13 +70,27 @@ def site_prefix(prefix: str):
         _CTX.prefix = prev
 
 
+@contextlib.contextmanager
+def site_hook(fn):
+    """Route every tagged value through ``fn(site, x) -> x`` while the
+    block runs (the pipelined layer stack captures residuals with it and
+    hands them back in the backward pass)."""
+    prev = _CTX.hook
+    _CTX.hook = fn
+    try:
+        yield
+    finally:
+        _CTX.hook = prev
+
+
 def tag(x, site: str):
     """Name ``x`` as an offloadable site.  The ``name`` equation sits in
     the named scope ``offload.<site>``; it lowers to no HLO op, and JAX
     gives the offload copies it derives a source info of their own."""
     assert site in SITE_INDEX, f"unknown site {site!r}"
     with jax.named_scope(f"offload.{site}"):
-        return checkpoint_name(x, _CTX.prefix + site)
+        x = checkpoint_name(x, _CTX.prefix + site)
+    return x if _CTX.hook is None else _CTX.hook(site, x)
 
 
 def base_site(name: str) -> str:

@@ -7,7 +7,9 @@ small and remat/offload policies apply per scan step.  Heterogeneous stacks
 
 ``policy`` threads a ``jax.checkpoint`` policy (produced by the Chameleon
 executor) into every scanned block — this is how a generated swap policy is
-*applied* to the training program.
+*applied* to the training program.  A policy that offloads
+(``OffloadSites``) is run by the dense and MoE stacks themselves, pipelined
+across layers (``repro.models.pipelined``), outside a mesh.
 """
 from __future__ import annotations
 
@@ -18,11 +20,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.config import ModelConfig
+from repro.core.executor import OffloadSites
 from repro.core.sites import tag
 from repro.distributed import sharding as shd
 from repro.models import attention as attn
 from repro.models import layers as L
 from repro.models import moe as moe_lib
+from repro.models import pipelined
 from repro.models import ssm as ssm_lib
 
 
@@ -136,6 +140,8 @@ def _maybe_ckpt(fn, policy):
         return fn
     if policy == "full_remat":
         return jax.checkpoint(fn)
+    if isinstance(policy, OffloadSites):
+        policy = policy.checkpoint_policy
     return jax.checkpoint(fn, policy=policy)
 
 
@@ -151,7 +157,13 @@ def forward(cfg: ModelConfig, params, tokens, *, positions=None,
     aux_total = jnp.zeros((), jnp.float32)
     fam = cfg.family
 
-    if fam in ("dense", "moe"):
+    if fam in ("dense", "moe") and isinstance(policy, OffloadSites) \
+            and shd.current_mesh() is None:
+        x, aux_total = pipelined.offloaded_scan(
+            lambda x, lp, pos: dense_block(cfg, lp, x, pos),
+            x, params["blocks"], positions, policy)
+
+    elif fam in ("dense", "moe"):
         def body(carry, lp):
             x, aux = carry
             x, a = dense_block(cfg, lp, x, positions)

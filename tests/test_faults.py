@@ -379,8 +379,9 @@ def test_ladder_reset_and_probe_throttle():
 
 def test_ladder_move_records_a_policy_install():
     """A rung the ladder moves to is a new applied policy: one
-    ``policy.install`` instant (arg ``ladder``) under ``runtime.ladder``,
-    counted in ``policy_installs``."""
+    ``policy.install`` instant (arg ``("ladder", True)``: the trimmed rung
+    still offloads, so its stack is pipelined) under ``runtime.ladder``,
+    counted in ``policy_installs`` and ``offload_pipelined_installs``."""
     from repro.common.config import ChameleonConfig
     from repro.core.executor import AppliedPolicy
     from repro.core.runtime import ChameleonRuntime
@@ -399,11 +400,13 @@ def test_ladder_move_records_a_policy_install():
         by = {r["id"]: r for r in obs.tracer().records()}
         installs = [r for r in by.values() if r["name"] == "policy.install"]
         assert [(r["lane"], r["kind"], r["arg"]) for r in installs] == [
-            (obs.LANE_ADAPT, "instant", "ladder")]
+            (obs.LANE_ADAPT, "instant", ("ladder", True))]
         ladder = by[installs[0]["parent"]]
         assert ladder["name"] == "runtime.ladder"
         assert by[ladder["parent"]]["name"] == "runtime.end_iteration"
-        assert obs.metrics().snapshot()["counters"]["policy_installs"] == 1
+        counters = obs.metrics().snapshot()["counters"]
+        assert counters["policy_installs"] == 1
+        assert counters["offload_pipelined_installs"] == 1
         rt.end_iteration(0.01)               # hold: no move, no install
         assert obs.metrics().snapshot()["counters"]["policy_installs"] == 1
     finally:

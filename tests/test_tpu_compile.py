@@ -11,6 +11,7 @@ test worker collects the same tests and only the worker given this file
 loads the TPU library.  Keep every such compile in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 import repro.configs as C
 from repro.common.config import ChameleonConfig, TrainConfig
-from repro.core.executor import Executor
+from repro.core.executor import Executor, OffloadSites
 from repro.distributed import steps as S
 from repro.kernels.autotune.space import SPACES
 from repro.kernels.flash_attention import kernel as FK
@@ -145,3 +146,83 @@ def test_qwen_apply_step_donates_state(one_chip):
     assert alias > 0
     # the device pads small buffers (the int32 step) to its alignment
     assert alias == pytest.approx(_nbytes(params) + _nbytes(opt), abs=4096)
+
+
+_ASYNC = re.compile(r"\s(dynamic-(?:update-)?slice-(?:start|done))\((%[\w.\-]+)")
+
+
+def _computations(hlo: str):
+    """name -> instruction lines of each computation of an HLO module."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line)
+    return comps
+
+
+def _host_transfer_windows(hlo: str):
+    """For each computation with pinned-host transfers: per transfer, the
+    count of matmul-bearing instructions (a dot, a convolution, or a
+    fusion that calls one) scheduled between its start and its done."""
+    comps = _computations(hlo)
+    memo = {}
+
+    def matmul(line):
+        if re.search(r"\s(convolution|dot)\(", line):
+            return True
+        m = re.search(r"\sfusion\(.*calls=(%[\w.\-]+)", line)
+        if not m:
+            return False
+        if m.group(1) not in memo:
+            memo[m.group(1)] = any(matmul(l) for l in comps.get(m.group(1), []))
+        return memo[m.group(1)]
+
+    windows = {}
+    for name, lines in comps.items():
+        starts, found = {}, []
+        for i, line in enumerate(lines):
+            m = _ASYNC.search(line)
+            if m and m.group(1).endswith("start") and "S(5)" in line:
+                starts[line.split(" = ")[0].strip().split()[-1]] = i
+            elif m and m.group(2) in starts:
+                s = starts[m.group(2)]
+                found.append(sum(map(matmul, lines[s + 1:i])))
+        if found:
+            windows[name] = found
+    return windows
+
+
+def test_qwen_pipelined_offload_overlaps_compute(one_chip):
+    """qwen1.5-0.5b at published widths, 2 x 2048 tokens, under the swap
+    cell's Stable policy: the pipelined stack schedules a matmul between
+    the start and the done of every pinned-host transfer, in the forward
+    and backward loop bodies alike; it moves as many host bytes as the
+    ``jax.checkpoint`` path and holds at most one more layer's residuals
+    (77.6 MB) in HBM."""
+    cfg, params, _ = _qwen_state(one_chip)
+    batch = {k: _sds(one_chip, (2, 2048), jnp.int32)
+             for k in ("tokens", "labels")}
+    sites = OffloadSites(
+        frozenset({"ffn_act", "ffn_pre", "ln_in"}),
+        frozenset({"attn_ctx", "attn_out", "embed_out", "final_norm",
+                   "qkv_proj"}))
+    compiled = {
+        name: jax.jit(S.make_grad_step(cfg, TrainConfig(), pol)).lower(
+            params, batch, _sds(one_chip, (), jnp.float32)).compile()
+        for name, pol in (("pipelined", sites),
+                          ("checkpoint", sites.checkpoint_policy))}
+    hlo = compiled["pipelined"].as_text()
+    windows = _host_transfer_windows(hlo)
+    loops = set(re.findall(r"body=(%[\w.\-]+)", hlo))
+    assert len(loops & set(windows)) >= 2, windows
+    assert all(n >= 1 for w in windows.values() for n in w), windows
+    ma, ref = (compiled[k].memory_analysis()
+               for k in ("pipelined", "checkpoint"))
+    assert ma.host_temp_size_in_bytes == ref.host_temp_size_in_bytes
+    assert ma.host_temp_size_in_bytes / GiB == pytest.approx(1.734, abs=1e-3)
+    assert ma.temp_size_in_bytes <= ref.temp_size_in_bytes + 0.08 * GiB
