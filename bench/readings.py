@@ -10,8 +10,9 @@ program, runs the float32 reference from the seed and prints
 ``bench.check``'s numbers.  For every control seed it also puts in the
 program's place, against the same reference:
 
-- ``control``: the reference computed with float8 matmul inputs, the
-  precision below the configuration's bfloat16, every step from the seed;
+- ``control``: the configuration's reference (``Cell.reference``)
+  computed with float8 matmul inputs, the precision below the
+  configuration's bfloat16, every step from the seed;
 - ``half_batch``: the reference's state before the compared steps, run
   through them on the first half of each batch's rows only, the mean taken
   over them (cells with more than one row);
@@ -68,7 +69,7 @@ def planted(cell: SPEC.Cell, seed: int, prog: dict, kind: str,
     import jax
     from bench import check
     from bench.data import TRAIN_STREAM, Batches
-    from bench.reference import Reference
+    Reference = cell.reference().Reference
     model, job = cell.model, cell.traffic
     first = prog["first"]
     if kind == "control":

@@ -15,6 +15,11 @@ No remat, no offload, no kernels: every matmul runs in float32 under
 time and layer by layer (a forward pass that keeps each layer's input,
 then each layer's VJP), and attention in blocks of query rows that the
 backward pass recomputes, so that a step at the timed sizes fits one chip.
+AdamW's moments stay on the device where the params, the gradients and
+both moments fit it beside one row's activations; otherwise they live on
+the host (numpy float32) between steps and the update runs one piece at a
+time (a leaf, or one layer's slice of a stacked leaf), so the device holds
+the params, the gradients and one piece's moments.
 
 ``precision="fp8"`` is the control: the same computation with both inputs
 of every matmul rounded to float8 (e4m3, one scale per tensor), the step
@@ -28,10 +33,24 @@ from typing import Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 F32 = jnp.float32
 FP8_MAX = 448.0          # largest finite float8_e4m3fn
 QUERY_BLOCK = 512        # query rows whose attention scores are live at once
+STACKED = "blocks"       # leaves under this key are stacked per layer
+# Device memory for one row's activations and layer VJP, beside the float32
+# params, gradients and both moments.
+HEADROOM_BYTES = 4 << 30
+
+
+def moments_fit(params) -> bool:
+    """Whether the float32 params, gradients and both Adam moments fit the
+    default device with ``HEADROOM_BYTES`` to spare (True where the device
+    states no limit)."""
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    need = 4 * sum(x.size * 4 for x in jax.tree.leaves(params))
+    return limit is None or need + HEADROOM_BYTES <= limit
 
 
 def _fp8(a):
@@ -219,14 +238,18 @@ class Reference:
         self._head = jax.jit(jax.value_and_grad(
             functools.partial(_head_loss, m, pr), argnums=(0, 1, 2)))
         # accumulators write in place: a step at the timed sizes holds the
-        # params, both Adam moments and the gradients at once
+        # params, the gradients and, where they fit, both Adam moments
         self._acc = jax.jit(lambda acc, g, s: jax.tree.map(
             lambda a, b: a + b * s, acc, g), donate_argnums=0)
         self._acc_layer = jax.jit(lambda acc, g, i: jax.tree.map(
             lambda a, b: a.at[i].add(b), acc, g), donate_argnums=0)
         self._acc_embed = jax.jit(lambda acc, t, ct: acc.at[t].add(ct),
                                   donate_argnums=0)
-        self._adamw = jax.jit(self._adamw_impl, donate_argnums=(0, 1, 2, 3))
+        self._clip = jax.jit(self._clip_impl)
+        self._coeffs = jax.jit(self._coeffs_impl)
+        self._leaf = jax.jit(self._leaf_impl, donate_argnums=(0, 2, 3))
+        self._layer_slice = jax.jit(self._layer_slice_impl,
+                                    donate_argnums=(0, 2, 3))
 
     def _layer_params(self, params, i: int):
         return jax.tree.map(lambda t: t[i], params["blocks"])
@@ -282,56 +305,123 @@ class Reference:
                                     * (1 + jnp.cos(jnp.pi * prog)))
         return jnp.where(step < j["warmup_steps"], warm, cos)
 
-    def _adamw_impl(self, params, grads, m, v, step):
-        """One update; returns the new params and moments, the step and
-        the clipping factor applied to ``grads``."""
-        j = self.job
-        b1, b2 = j["adam_b1"], j["adam_b2"]
+    def _clip_impl(self, grads):
+        """The clipping factor, from the global norm over every leaf."""
         gnorm = jnp.sqrt(sum(jnp.sum(g * g)
                              for g in jax.tree_util.tree_leaves(grads)))
-        scale = jnp.minimum(1.0, j["grad_clip"] / jnp.maximum(gnorm, 1e-12))
+        return jnp.minimum(1.0, self.job["grad_clip"]
+                           / jnp.maximum(gnorm, 1e-12))
+
+    def _coeffs_impl(self, step):
+        """The learning rate of ``step``, the next step and the bias
+        corrections at it."""
+        j = self.job
         lr = self._lr(step)
         step = step + 1
-        c1 = 1.0 - b1 ** step.astype(F32)
-        c2 = 1.0 - b2 ** step.astype(F32)
-        m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g * scale,
-                         m, grads)
-        v = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * (g * scale) ** 2,
-                         v, grads)
-        params = jax.tree.map(
-            lambda p, m, v: p - lr * ((m / c1) / (jnp.sqrt(v / c2)
-                                                  + j["adam_eps"])
-                                      + j["weight_decay"] * p),
-            params, m, v)
-        return params, m, v, step, scale
+        c1 = 1.0 - j["adam_b1"] ** step.astype(F32)
+        c2 = 1.0 - j["adam_b2"] ** step.astype(F32)
+        return lr, step, c1, c2
+
+    def _leaf_impl(self, p, g, m, v, scale, lr, c1, c2):
+        """AdamW on one piece: the new params and moments."""
+        j = self.job
+        b1, b2 = j["adam_b1"], j["adam_b2"]
+        m = b1 * m + (1 - b1) * g * scale
+        v = b2 * v + (1 - b2) * (g * scale) ** 2
+        p = p - lr * ((m / c1) / (jnp.sqrt(v / c2) + j["adam_eps"])
+                      + j["weight_decay"] * p)
+        return p, m, v
+
+    def _layer_slice_impl(self, p, g, m, v, i, scale, lr, c1, c2):
+        """AdamW on layer ``i`` of a stacked leaf, written in place."""
+        pi, m, v = self._leaf_impl(p[i], g[i], m, v, scale, lr, c1, c2)
+        return p.at[i].set(pi), m, v
+
+    def _adamw(self, params, grads, m, v, step):
+        """One update of ``params`` (consumed) from ``grads``, the moments
+        ``m`` and ``v`` and ``step``.  Returns the new params and moments,
+        the next step and the clipping factor applied to ``grads``.  The
+        clipping factor comes first, from every gradient leaf.  Moments on
+        the device (consumed) are updated a leaf at a time.  Moments on the
+        host (trees of numpy float32 arrays) are updated in place: each
+        piece (a leaf, or one layer's slice of a stacked leaf) goes to the
+        device with its moments, and its moments come back to the host
+        while the next piece runs."""
+        scale = self._clip(grads)
+        lr, step, c1, c2 = self._coeffs(step)
+        flat, tree = jax.tree_util.tree_flatten_with_path(params)
+        g_flat, m_flat, v_flat = (jax.tree_util.tree_leaves(t)
+                                  for t in (grads, m, v))
+        if not isinstance(m_flat[0], np.ndarray):
+            new = [self._leaf(p, g, md, vd, scale, lr, c1, c2)
+                   for (_, p), g, md, vd in zip(flat, g_flat, m_flat, v_flat)]
+            params, m, v = (jax.tree_util.tree_unflatten(tree, list(t))
+                            for t in zip(*new))
+            return params, m, v, step, scale
+        out, pending = [], None
+        for (path, p), g, mh, vh in zip(flat, g_flat, m_flat, v_flat):
+            if getattr(path[0], "key", None) == STACKED:
+                for i in range(p.shape[0]):
+                    p, md, vd = self._layer_slice(
+                        p, g, jax.device_put(mh[i]), jax.device_put(vh[i]),
+                        jnp.int32(i), scale, lr, c1, c2)
+                    pending = self._fetch(pending, mh, vh, i, md, vd)
+            else:
+                p, md, vd = self._leaf(p, g, jax.device_put(mh),
+                                       jax.device_put(vh), scale, lr, c1, c2)
+                pending = self._fetch(pending, mh, vh, Ellipsis, md, vd)
+            out.append(p)
+        self._fetch(pending)
+        return jax.tree_util.tree_unflatten(tree, out), m, v, step, scale
+
+    @staticmethod
+    def _fetch(pending, *piece):
+        """Start copying ``piece``'s new moments to the host, then write
+        the previous piece's into its host arrays; returns ``piece``."""
+        if piece:
+            for x in piece[-2:]:
+                x.copy_to_host_async()
+        if pending is not None:
+            mh, vh, i, md, vd = pending
+            mh[i], vh[i] = np.asarray(md), np.asarray(vd)
+        return piece or None
 
     def train(self, params, batches: List[dict], norms: Callable,
               first: int = 0, state: Optional[tuple] = None,
               keep_state: bool = False) -> dict:
         """One step per batch from ``params`` (consumed) and the AdamW
-        ``state`` ``(m, v, step)`` (zeros at step 0 when None).  Returns
-        the losses, ``norms`` of step ``first``'s gradient as clipped for
-        the optimizer, the params before that step (``start``, on the
-        host) and the final params; with ``keep_state`` also ``state``,
-        the AdamW state before step ``first`` (on the host), from which
-        the steps after it can be run again."""
+        ``state`` ``(m, v, step)`` (zeros at step 0 when None; copied, so
+        ``state`` can start another run).  Returns the losses, ``norms``
+        of step ``first``'s gradient as clipped for the optimizer, the
+        params before that step (``start``, on the host) and the final
+        params; with ``keep_state`` also ``state``, the AdamW state before
+        step ``first`` (on the host), from which the steps after it can be
+        run again."""
+        host = lambda t: jax.tree.map(  # noqa: E731
+            lambda x: np.array(x, np.float32), t)
+        on_host = not moments_fit(params)
         if state is None:
-            m = jax.tree.map(jnp.zeros_like, params)
-            v = jax.tree.map(jnp.zeros_like, params)
+            zeros = ((lambda p: np.zeros(p.shape, np.float32)) if on_host
+                     else jnp.zeros_like)
+            m, v = jax.tree.map(zeros, params), jax.tree.map(zeros, params)
             step = jnp.zeros((), jnp.int32)
         else:
-            m, v, step = (jax.device_put(x) for x in state)
+            m, v, step = host(state[0]), host(state[1]), jnp.asarray(
+                state[2], jnp.int32)
+            if not on_host:
+                m, v = jax.device_put((m, v))
         out = {"losses": []}
         for i, batch in enumerate(batches):
             if i == first:
                 out["start"] = jax.device_get(params)
                 if keep_state:
-                    out["state"] = jax.device_get((m, v, step))
+                    out["state"] = (host(m), host(v), jax.device_get(step))
             loss, grads = self.loss_and_grads(params, batch)
             out["losses"].append(loss)
             raw = norms(grads) if i == first else None
             params, m, v, step, scale = self._adamw(params, grads, m, v,
                                                     step)
+            del grads
             if raw is not None:
                 out["grad"] = {k: n * float(scale) for k, n in raw.items()}
         del m, v

@@ -10,18 +10,19 @@ In one process on the chips the cell asks for:
    ``.jax_cache``.
 3. Set-up builds the cell's ``Trainer`` (weights from ``--seed``, batches
    from ``bench.data``) with an HBM budget of ``bytes_limit`` less the
-   AdamW state less 2 GiB, and trains until the stage machine has reached
-   Stable; then three more steps, whose losses, first gradient and change
-   of the parameters the correctness check compares: the steps of the
-   grad step that the window times.  A cell with an eval cadence then
-   runs two whole eval cycles, so every program the window uses is
-   compiled before it opens.
+   AdamW state it holds in device memory less 2 GiB, and trains until the
+   stage machine has reached Stable; then three more steps, whose losses,
+   first gradient and change of the parameters the correctness check
+   compares: the steps of the grad step that the window times.  A cell
+   with an eval cadence then runs two whole eval cycles, so every program
+   the window uses is compiled before it opens.
 4. The window calls ``Trainer.train(1)`` until ``--seconds`` have passed
    (and, with an eval cadence, a cycle has closed).  With ``--trace 1`` the
    window is traced and the calls into each layer carry host spans.
 5. After the window: the device memory peak, then the program's state is
-   freed and the float32 reference runs every step from the seed again
-   (``bench.check``).  The last line of stdout is one JSON object.
+   freed and the configuration's float32 reference runs every step from
+   the seed again (``bench.check``).  The last line of stdout is one JSON
+   object.
 """
 from __future__ import annotations
 
@@ -39,7 +40,7 @@ import os
 import shutil
 import sys
 import tempfile
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 if __name__ == "__main__":
     # Run as a script: import ``bench`` from the checkout's root, not from
@@ -50,9 +51,11 @@ from bench import spec as SPEC
 
 ROOT = SPEC.ROOT
 GiB = 1 << 30
-# The runtime budgets activations only; the AdamW state stays resident
-# beside them.  2 GiB covers what its jaxpr-level profile cannot see.
+# The runtime budgets activations only; the AdamW state it holds in device
+# memory stays resident beside them.  2 GiB covers what its jaxpr-level
+# profile cannot see.
 MARGIN_BYTES = 2 * GiB
+HOST_KINDS = ("pinned_host", "unpinned_host")
 REF_STEPS = 3                # Stable steps the check compares
 STABLE_ITERS = 2             # Stable iterations before the compared steps
 MAX_SETUP_ITERS = 48
@@ -110,9 +113,36 @@ def tree_bytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
+def placed_bytes(tree) -> Tuple[int, int]:
+    """Bytes of ``tree``'s leaves in device memory and in host memory, by
+    each leaf's ``sharding.memory_kind``."""
+    import jax
+    device = host = 0
+    for x in jax.tree.leaves(tree):
+        kind = getattr(getattr(x, "sharding", None), "memory_kind", None)
+        if kind in HOST_KINDS:
+            host += x.size * x.dtype.itemsize
+        else:
+            device += x.size * x.dtype.itemsize
+    return device, host
+
+
+def hbm_budget(limit_bytes: int, opt_state) -> int:
+    """``limit_bytes`` less the AdamW state held in device memory, less
+    ``MARGIN_BYTES``."""
+    return limit_bytes - placed_bytes(opt_state)[0] - MARGIN_BYTES
+
+
 def build(cell: SPEC.Cell, seed: int, limit_bytes: int, ckpt_dir: str):
     """The cell's Trainer, built as ``launch/train.build_trainer`` builds
-    one, from the cell's files instead of ``--arch``."""
+    one, from the cell's files instead of ``--arch``.
+
+    The runtime's budget is set from the state the built Trainer holds:
+    its ``ChameleonConfig`` gets the budget with the whole AdamW state in
+    HBM (all that the program shows before it places the state), and once
+    built, the runtime's ``budget``, which every policy and OOM pass of the
+    runtime is handed, gets the one from the leaves in device memory.  No
+    iteration runs in between."""
     import jax
     from repro.common.config import ChameleonConfig, TrainConfig
     from repro.distributed import steps as S
@@ -123,9 +153,6 @@ def build(cell: SPEC.Cell, seed: int, limit_bytes: int, ckpt_dir: str):
     cfg = model_config(cell)
     job = cell.traffic
     opt_bytes = tree_bytes(jax.eval_shape(adamw_init, S.abstract_params(cfg)))
-    budget = limit_bytes - opt_bytes - MARGIN_BYTES
-    if budget <= 0:
-        raise RuntimeError(f"no HBM left for activations: budget {budget}")
     tcfg = TrainConfig(steps=job["total_steps"],
                        learning_rate=job["learning_rate"],
                        warmup_steps=job["warmup_steps"],
@@ -133,12 +160,19 @@ def build(cell: SPEC.Cell, seed: int, limit_bytes: int, ckpt_dir: str):
                        grad_clip=job["grad_clip"], eval_every=0,
                        checkpoint_every=0, checkpoint_dir=ckpt_dir,
                        seed=seed)
-    tr = Trainer(cfg, tcfg, ChameleonConfig(hbm_budget_bytes=budget),
+    cham = ChameleonConfig(
+        hbm_budget_bytes=limit_bytes - opt_bytes - MARGIN_BYTES)
+    tr = Trainer(cfg, tcfg, cham,
                  data=Batches(cfg.vocab_size, job, seed, TRAIN_STREAM),
                  eval_data=Batches(cfg.vocab_size, job, seed, EVAL_STREAM))
+    budget = hbm_budget(limit_bytes, tr.opt_state)
+    host_bytes = placed_bytes(tr.opt_state)[1]
+    if budget <= 0:
+        raise RuntimeError(f"no HBM left for activations: budget {budget}")
+    tr.rt.budget = budget
     log(f"build: {cell.name} seed {seed}: {cfg.param_count() / 1e6:.1f}M "
-        f"params, AdamW {opt_bytes / GiB:.3f} GiB, budget {budget} B "
-        f"({budget / GiB:.3f} GiB)")
+        f"params, AdamW {opt_bytes / GiB:.3f} GiB ({host_bytes} B on the "
+        f"host), budget {budget} B ({budget / GiB:.3f} GiB)")
     return tr
 
 
@@ -299,16 +333,17 @@ def reference_numbers(cell: SPEC.Cell, seed: int, batches: List[int],
                       first: int, ref=None, keep_state: bool = False
                       ) -> dict:
     """The losses, gradient and change norms (``bench.check``) of ``ref``
-    (default: the float32 reference) run from the seed on the batches the
-    program trained on, comparing from step ``first``; with
-    ``keep_state``, also the params and AdamW state before that step."""
+    (default: the configuration's float32 reference, ``Cell.reference``)
+    run from the seed on the batches the program trained on, comparing
+    from step ``first``; with ``keep_state``, also the params and AdamW
+    state before that step."""
     from bench import check
     from bench.data import TRAIN_STREAM, Batches
-    from bench.reference import Reference, init_params
     model, job = cell.model, cell.traffic
-    ref = ref or Reference(model, job)
+    reference = cell.reference()
+    ref = ref or reference.Reference(model, job)
     feed = Batches(model["vocab_size"], job, seed, TRAIN_STREAM)
-    out = ref.train(init_params(model, seed),
+    out = ref.train(reference.init_params(model, seed),
                     [feed.batch_at(i) for i in batches], check.leaf_norms,
                     first, keep_state=keep_state)
     out["update"] = check.diff_norms(out.pop("params"), out["start"])

@@ -4,23 +4,30 @@ A cell is one entry of ``workloads``.  Everything that belongs to it is a
 file of its own, found from the names in that entry:
 
 - the configuration: the ``file`` of the ``configs`` entry it names;
+- the configuration's float32 reference: ``bench/references/<config>.py``
+  where that file exists, a module with a ``Reference`` class and
+  ``init_params``; else ``bench/reference.py`` (the dense Qwen decoder);
 - the traffic: ``bench/traffic/<traffic>.json``;
 - its correctness limits: ``bench/workloads/<cell>.json``;
 - each per-layer metric: a reader ``bench/metrics/<metric>.py``.
 
-So a later cell, configuration or metric is added as files and entries,
-without editing a file that is there.
+So a later cell, configuration, reference for another architecture or
+metric is added as files and entries, without editing a file that is
+there.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 ROOT = Path(__file__).resolve().parents[1]
+DEFAULT_REFERENCE = "bench.reference"
 NAME_RE = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 UNIT_RE = re.compile(r"[A-Za-z0-9_/%.-]{1,16}")
 
@@ -28,6 +35,21 @@ UNIT_RE = re.compile(r"[A-Za-z0-9_/%.-]{1,16}")
 def _json(path: Path):
     with open(path) as f:
         return json.load(f)
+
+
+_MODULES: Dict[Path, ModuleType] = {}
+
+
+def _module(path: Path, name: str) -> ModuleType:
+    """The module of the file ``path``, executed once per process."""
+    path = path.resolve()
+    if path not in _MODULES:
+        spec = importlib.util.spec_from_file_location(
+            name + re.sub(r"\W", "_", path.stem), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
 
 
 @dataclass
@@ -47,12 +69,18 @@ class Cell:
 
     def reader(self, metric: str) -> Callable:
         """``read`` of ``bench/metrics/<metric>.py``."""
-        path = self.root / "bench" / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + re.sub(r"\W", "_", metric), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _module(self.root / "bench" / "metrics" / f"{metric}.py",
+                       "bench_metric_").read
+
+    def reference(self) -> ModuleType:
+        """The configuration's reference module:
+        ``bench/references/<config>.py`` where it exists, else
+        ``bench.reference``."""
+        path = (self.root / "bench" / "references"
+                / f"{self.entry.get('config')}.py")
+        if path.is_file():
+            return _module(path, "bench_reference_")
+        return importlib.import_module(DEFAULT_REFERENCE)
 
 
 def load(root: Path = ROOT) -> dict:
